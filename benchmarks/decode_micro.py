@@ -26,16 +26,16 @@ of through the virtual-clock serving simulation:
    continuation reads every block the prefill wrote, so it catches any
    KV-scatter divergence, not just logit agreement at the last position).
 
-    PYTHONPATH=src python benchmarks/decode_micro.py
-    PYTHONPATH=src python benchmarks/decode_micro.py --smoke   # CI: tiny
+    PYTHONPATH=src python benchmarks/decode_micro.py            # TPU
+    PYTHONPATH=src python benchmarks/decode_micro.py --smoke --interpret
 
 Results are written machine-readable to ``BENCH_decode.json`` (schema
 asserted by ``tools/check_bench.py``; metric glossary in
-docs/benchmarks.md).  On this CPU container the kernels run in interpret
-mode, so absolute microseconds measure Python/XLA dispatch overhead rather
-than MXU throughput — the fetch counts and dispatch counts are the
-hardware-independent claims; on a TPU backend the same script times the
-compiled Mosaic kernels.
+docs/benchmarks.md).  On a TPU the kernel sweep times the compiled Mosaic
+kernels.  On the CPU the kernels run only in the Pallas interpreter, and
+only when ``--interpret`` asks for it: absolute microseconds then measure
+Python/XLA dispatch overhead rather than MXU throughput, and the fetch and
+dispatch counts are the hardware-independent claims.
 """
 from __future__ import annotations
 
@@ -55,6 +55,7 @@ import jax.numpy as jnp                                         # noqa: E402
 from repro.configs import get_config, reduced_config            # noqa: E402
 from repro.kernels import ops                                   # noqa: E402
 from repro.kernels.flash_attention import paged_kv_fetches      # noqa: E402
+from repro.launch.compile_cache import use_compile_cache        # noqa: E402
 from repro.launch.serve import KVBlockPool, LMBackend           # noqa: E402
 
 
@@ -81,8 +82,8 @@ def kernel_sweep(cases, *, b: int, ctx_blocks: int, d: int, reps: int,
         n_blocks = b * m + 1                        # block 0 = trash
         k1, k2, k3 = jax.random.split(key, 3)
         q = jax.random.normal(k1, (b, 1, hq, d), jnp.float32)
-        kp = jax.random.normal(k2, (n_blocks, bs, hkv, d), jnp.float32)
-        vp = jax.random.normal(k3, (n_blocks, bs, hkv, d), jnp.float32)
+        kp = jax.random.normal(k2, (n_blocks, hkv, bs, d), jnp.float32)
+        vp = jax.random.normal(k3, (n_blocks, hkv, bs, d), jnp.float32)
         tables = jnp.asarray(
             1 + np.arange(b * m, dtype=np.int32).reshape(b, m))
         lens = jnp.full((b,), m * bs, jnp.int32)
@@ -466,7 +467,11 @@ def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="smollm-360m")
     ap.add_argument("--smoke", action="store_true",
-                    help="tiny shapes for CI (interpret mode)")
+                    help="tiny shapes for CI")
+    ap.add_argument("--interpret", action="store_true",
+                    help="run the kernel sweep in the Pallas interpreter "
+                         "(the CPU); without it the kernels compile for "
+                         "the TPU")
     ap.add_argument("--reps", type=int, default=0,
                     help="timing repetitions (0 = auto)")
     ap.add_argument("--draft-cost", type=float, default=0.1,
@@ -476,8 +481,9 @@ def main() -> int:
     ap.add_argument("--json", default="BENCH_decode.json",
                     help="output artifact path ('' to disable)")
     args = ap.parse_args()
+    use_compile_cache()
 
-    interpret = jax.default_backend() != "tpu"
+    interpret = args.interpret
     reps = args.reps or (3 if args.smoke else 15)
     if args.smoke:
         cases = [(2, 1, 8), (4, 2, 8)]

@@ -84,6 +84,7 @@ from repro.core.clones import (CLONE_TYPES, OFF_IDLE_TTL,       # noqa: E402
 from repro.core.policy import Policy                            # noqa: E402
 from repro.core.scheduler import (ServeRequest,                 # noqa: E402
                                   poisson_arrivals)
+from repro.launch.compile_cache import use_compile_cache        # noqa: E402
 from repro.launch.serve import ClientHandler, LMBackend         # noqa: E402
 
 HEADER = (f"{'rate_rps':>8s} {'kv':>10s} {'served':>6s} {'shed':>5s} "
@@ -1061,6 +1062,7 @@ def main() -> None:
     ap.add_argument("--json", default="BENCH_serving.json",
                     help="machine-readable output path ('' to skip)")
     args = ap.parse_args()
+    use_compile_cache()
 
     modes = (("paged", "contiguous") if args.kv == "both" else (args.kv,))
     lines, rows = run_sweep(args.arch, tuple(args.rates), args.requests,

@@ -248,6 +248,10 @@ class Clone:
                 and self.breaker.state == "closed")
 
 
+class ClonePoolExhausted(RuntimeError):
+    """``ClonePool.acquire`` needs more clones than ``max_clones`` allows."""
+
+
 class ClonePool:
     """On-demand allocation of clones (paper §5.3), primary + secondaries."""
 
@@ -367,7 +371,7 @@ class ClonePool:
                 to_boot.append(c)
         while len(ready) + len(to_resume) + len(to_boot) < n:
             if len(self.clones) >= self.max_clones:
-                raise RuntimeError("clone pool exhausted")
+                raise ClonePoolExhausted("clone pool exhausted")
             to_boot.append(self._new_clone(type_name))
 
         cost = 0.0

@@ -29,21 +29,21 @@ def dequantize_int8(q: jax.Array, scale: jax.Array) -> jax.Array:
 def kv_quant_axes(ndim: int) -> Tuple[int, ...]:
     """Reduction axes for per-(block, head) KV scales.
 
-    Gathered KV blocks are laid out ``(n_blocks, block_size, n_kv_heads,
-    head_dim)`` (pool layout with the block axis moved to 0); the scale
-    must survive per block AND per head, so reduce every axis except 0
-    and the head axis at -2.  Leaves too small to carry a head axis
-    (ndim < 3) fall back to per-block scales.
+    Gathered KV blocks are laid out ``(n_blocks, ..., n_kv_heads,
+    block_size, head_dim)`` (the head-major pool layout with the block
+    axis moved to 0); the scale must survive per block AND per head, so
+    reduce every axis except 0 and the head axis at -3.  Leaves too small
+    to carry a head axis (ndim < 4) fall back to per-block scales.
     """
-    if ndim >= 3:
-        return tuple(i for i in range(1, ndim) if i != ndim - 2)
+    if ndim >= 4:
+        return tuple(i for i in range(1, ndim) if i != ndim - 3)
     return tuple(range(1, ndim))
 
 
 def quantize_kv_blocks(blocks: jax.Array) -> Tuple[jax.Array, jax.Array]:
     """Per-(block, head) int8 quantization of gathered KV blocks.
 
-    ``blocks``: (n, ...) gathered along the block axis (head axis at -2
+    ``blocks``: (n, ...) gathered along the block axis (head axis at -3
     when present).  Returns ``(q int8, scales float32)`` with ``scales``
     keepdims-shaped so it broadcasts against ``blocks`` — the compressed
     KV transfer ships 1 byte/element plus one float32 scale per

@@ -13,19 +13,6 @@ import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
-def shard_map_compat(body, *, mesh, in_specs, out_specs):
-    """``jax.shard_map`` across jax versions (replication checks off).
-
-    jax >= 0.6 exposes it as ``jax.shard_map(check_vma=...)``; older
-    releases as ``jax.experimental.shard_map.shard_map(check_rep=...)``.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(body, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
-
 # logical axis -> preferred mesh axis / tuple of axes (None = replicated)
 DEFAULT_RULES: Dict[str, object] = {
     # data-parallel dims
@@ -63,21 +50,6 @@ ZERO_SP_RULES: Dict[str, object] = dict(
     DEFAULT_RULES,
     heads=None, kv_heads=None, mlp=None, lru=None, rank=None,
 )
-
-
-def abstract_mesh(axis_sizes: Sequence[int], axis_names: Sequence[str]):
-    """Version-portable ``jax.sharding.AbstractMesh`` constructor.
-
-    JAX changed the signature across releases: older versions take
-    ``(axis_sizes, axis_names)``, 0.4.36+ takes a single tuple of
-    ``(name, size)`` pairs.  ``spec_for`` only needs ``mesh.shape``
-    (name -> size), which both spellings provide.
-    """
-    sizes, names = tuple(axis_sizes), tuple(axis_names)
-    try:
-        return jax.sharding.AbstractMesh(tuple(zip(names, sizes)))
-    except TypeError:
-        return jax.sharding.AbstractMesh(sizes, names)
 
 
 # serve profile (§Perf H2b): params resident (model-axis TP dims only, no

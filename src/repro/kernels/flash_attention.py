@@ -77,6 +77,34 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
         o_ref[0, 0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
 
 
+def _online_softmax_step(s, v, m_ref, l_ref, acc_ref):
+    """Fold one (rows, bs) score tile and its (bs, d) values into the
+    running softmax state: m, l are (rows, 1) and acc is (rows, d).
+
+    Every value is 2-D: Mosaic lays vectors out in (sublane, lane) tiles
+    and refuses to reshape a 1-D row vector (or mask) into a column, so
+    reductions keep their dims instead."""
+    m_prev = m_ref[...]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    p = jnp.exp(s - m_new)
+    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot(
+        p, v, preferred_element_type=jnp.float32)
+    m_ref[...] = m_new
+
+
+def _paged_scores(q_ref, k_ref, *, scale: float, softcap: Optional[float]):
+    """(rows, d) query tile x (bs, d) key block -> (rows, bs) scores."""
+    q = q_ref[...].astype(jnp.float32)
+    k = k_ref[...].astype(jnp.float32)
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * scale
+    if softcap is not None:
+        s = softcap * jnp.tanh(s / softcap)
+    return s
+
+
 def _paged_decode_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref, m_ref,
                          l_ref, acc_ref, *, scale: float,
                          softcap: Optional[float], bs: int, nblk: int):
@@ -94,8 +122,10 @@ def _paged_decode_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref, m_ref,
     The block table and context lengths arrive as scalar prefetch so the
     KV BlockSpec index map can chase ``tbl_ref`` — only the blocks a slot
     actually owns are ever staged into VMEM; there is no materialized
-    (B, M*bs, ...) gather.  Online-softmax state ((g,)/(g, d) m, l, acc)
-    persists in VMEM scratch across the sequential block grid dimension.
+    (B, M*bs, ...) gather.  The pool is head-major, so one staged block is
+    a contiguous (bs, d) tile of one KV head.  Online-softmax state
+    ((g, 1)/(g, d) m, l, acc) persists in VMEM scratch across the
+    sequential block grid dimension.
     """
     del tbl_ref                                   # consumed by the index maps
     b = pl.program_id(0)
@@ -110,34 +140,21 @@ def _paged_decode_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref, m_ref,
 
     @pl.when(j * bs < ctx)                        # block holds written slots
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)                  # (g, d)
-        k = k_ref[0, :, 0].astype(jnp.float32)               # (bs, d)
-        v = v_ref[0, :, 0].astype(jnp.float32)               # (bs, d)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        if softcap is not None:
-            s = softcap * jnp.tanh(s / softcap)
-        k_pos = j * bs + jax.lax.iota(jnp.int32, bs)
-        s = jnp.where((k_pos < ctx)[None, :], s, _NEG_INF)
-
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, None])
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot(
-            p, v, preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
+        s = _paged_scores(q_ref, k_ref, scale=scale, softcap=softcap)
+        k_pos = j * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(k_pos < ctx, s, _NEG_INF)
+        _online_softmax_step(s, v_ref[...].astype(jnp.float32), m_ref,
+                             l_ref, acc_ref)
 
     @pl.when(j == nblk - 1)
     def _finalize():
         l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[...] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 def _paged_chunk_kernel(tbl_ref, p0_ref, nl_ref, q_ref, k_ref, v_ref, o_ref,
                         m_ref, l_ref, acc_ref, *, scale: float,
-                        softcap: Optional[float], bs: int, g: int, c: int,
+                        softcap: Optional[float], bs: int, g: int,
                         nblk: int):
     """One (slot, head-group, kv-block) step of chunked-prefill attention.
 
@@ -150,9 +167,10 @@ def _paged_chunk_kernel(tbl_ref, p0_ref, nl_ref, q_ref, k_ref, v_ref, o_ref,
 
     Query row ``r`` is chunk position ``r // g`` at absolute position
     ``p0 + r // g``; causal masking admits key position ``k_pos`` when
-    ``k_pos <= p0 + r // g``, which covers both the previously resident
-    prefix blocks and intra-chunk causality in one predicate.  Rows past
-    the chunk's live length (``r // g >= nl``) are forced to zero in the
+    ``k_pos <= p0 + r // g``, i.e. ``r >= (k_pos - p0) * g``, which covers
+    both the previously resident prefix blocks and intra-chunk causality in
+    one predicate (written without a vector division).  Rows past the
+    chunk's live length (``r >= nl * g``) are forced to zero in the
     finalize step, matching the ref oracle's post-softmax re-mask.  Blocks
     entirely beyond the chunk's reach are predicated off with ``pl.when``;
     they are exact no-ops for live rows because block j=0 always computes
@@ -170,38 +188,28 @@ def _paged_chunk_kernel(tbl_ref, p0_ref, nl_ref, q_ref, k_ref, v_ref, o_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    row = jax.lax.iota(jnp.int32, c * g)
-    q_chunk = row // g                            # chunk position per q row
-
     @pl.when((nl > 0) & (j * bs < p0 + nl))       # block reachable by chunk
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)                  # (c*g, d)
-        k = k_ref[0, :, 0].astype(jnp.float32)               # (bs, d)
-        v = v_ref[0, :, 0].astype(jnp.float32)               # (bs, d)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        if softcap is not None:
-            s = softcap * jnp.tanh(s / softcap)
-        k_pos = j * bs + jax.lax.iota(jnp.int32, bs)
-        ok = (q_chunk < nl)[:, None] & \
-            (k_pos[None, :] <= (p0 + q_chunk)[:, None])
+        s = _paged_scores(q_ref, k_ref, scale=scale, softcap=softcap)
+        row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        k_pos = j * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        ok = (row < nl * g) & (row >= (k_pos - p0) * g)
         s = jnp.where(ok, s, _NEG_INF)
-
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, None])
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot(
-            p, v, preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
+        _online_softmax_step(s, v_ref[...].astype(jnp.float32), m_ref,
+                             l_ref, acc_ref)
 
     @pl.when(j == nblk - 1)
     def _finalize():
         l = jnp.maximum(l_ref[...], 1e-30)
-        live = (q_chunk < nl).astype(jnp.float32)
-        o_ref[0, 0] = ((acc_ref[...] / l[:, None])
-                       * live[:, None]).astype(o_ref.dtype)
+        row = jax.lax.broadcasted_iota(jnp.int32, acc_ref.shape, 0)
+        o_ref[...] = jnp.where(row < nl * g, acc_ref[...] / l,
+                               0.0).astype(o_ref.dtype)
+
+
+def _paged_scratch(rows: int, d: int):
+    return [pltpu.VMEM((rows, 1), jnp.float32),
+            pltpu.VMEM((rows, 1), jnp.float32),
+            pltpu.VMEM((rows, d), jnp.float32)]
 
 
 def paged_prefill_bhsd(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
@@ -213,7 +221,7 @@ def paged_prefill_bhsd(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
 
     q: (B, Hq, C, D) — one C-token suffix chunk per slot (the chunk's KV
     must already be scattered into the pool);
-    k_pool, v_pool: (N, bs, Hkv, D) — the shared physical block pool;
+    k_pool, v_pool: (N, Hkv, bs, D) — the shared head-major block pool;
     block_tables: (B, M) int32 — per-slot physical block ids, logical order;
     pos0: (B,) int32 — absolute position of each chunk's first token;
     n_live: (B,) int32 — live tokens in the chunk (0..C; 0 = dead row).
@@ -233,39 +241,37 @@ def paged_prefill_bhsd(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     same MXU tile, never an extra kernel call or KV fetch.
     """
     b, hq, c, d = q.shape
-    _, bs, hkv, _ = k_pool.shape
+    _, hkv, bs, _ = k_pool.shape
     m = block_tables.shape[1]
     g = hq // hkv
     # (B, Hq, C, D) -> (B, Hkv, C*g, D): row r = chunk pos r // g, head r % g
     qg = q.reshape(b, hkv, g, c, d).swapaxes(2, 3).reshape(b, hkv, c * g, d)
 
+    def q_map(b_, h, j, tbl, p0, nl):
+        return (b_, h, 0, 0)
+
     def kv_map(b_, h, j, tbl, p0, nl):
-        return (tbl[b_, j], 0, h, 0)
+        return (tbl[b_, j], h, 0, 0)
 
     kern = functools.partial(_paged_chunk_kernel, scale=d ** -0.5,
-                             softcap=softcap, bs=bs, g=g, c=c, nblk=m)
+                             softcap=softcap, bs=bs, g=g, nblk=m)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(b, hkv, m),
         in_specs=[
-            pl.BlockSpec((1, 1, c * g, d), lambda b_, h, j, tbl, p0, nl:
-                         (b_, h, 0, 0)),
-            pl.BlockSpec((1, bs, 1, d), kv_map),
-            pl.BlockSpec((1, bs, 1, d), kv_map),
+            pl.BlockSpec((None, None, c * g, d), q_map),
+            pl.BlockSpec((None, None, bs, d), kv_map),
+            pl.BlockSpec((None, None, bs, d), kv_map),
         ],
-        out_specs=pl.BlockSpec((1, 1, c * g, d), lambda b_, h, j, tbl, p0, nl:
-                               (b_, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((c * g,), jnp.float32),
-            pltpu.VMEM((c * g,), jnp.float32),
-            pltpu.VMEM((c * g, d), jnp.float32),
-        ],
+        out_specs=pl.BlockSpec((None, None, c * g, d), q_map),
+        scratch_shapes=_paged_scratch(c * g, d),
     )
     out = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, c * g, d), q.dtype),
         interpret=interpret,
+        name="paged_prefill",
     )(block_tables, pos0, n_live, qg, k_pool, v_pool)
     return out.reshape(b, hkv, c, g, d).swapaxes(2, 3).reshape(b, hq, c, d)
 
@@ -290,7 +296,7 @@ def paged_attention_bhsd(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     """Decode-time paged attention over a block-table KV pool.
 
     q: (B, Hq, 1, D) — one query token per slot;
-    k_pool, v_pool: (N, bs, Hkv, D) — the shared physical block pool;
+    k_pool, v_pool: (N, Hkv, bs, D) — the shared head-major block pool;
     block_tables: (B, M) int32 — per-slot physical block ids, logical order;
     context_lens: (B,) int32 — tokens valid per slot.  Returns (B, Hq, 1, D).
 
@@ -300,7 +306,7 @@ def paged_attention_bhsd(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     A/B measurement (see benchmarks/decode_micro.py).
     """
     b, hq, _, d = q.shape
-    _, bs, hkv, _ = k_pool.shape
+    _, hkv, bs, _ = k_pool.shape
     m = block_tables.shape[1]
     g = hq // hkv
     # one kernel body, two grids: fused walks KV heads with (g, d) query
@@ -309,36 +315,33 @@ def paged_attention_bhsd(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     gq = g if fused else 1                        # query rows per grid step
     hg = hkv if fused else hq                     # head grid dimension
     qg = q[:, :, 0, :].reshape(b, hg, gq, d)
-    if fused:
-        def kv_map(b_, h, j, tbl, cl):
-            return (tbl[b_, j], 0, h, 0)
-    else:
-        def kv_map(b_, h, j, tbl, cl):
-            return (tbl[b_, j], 0, h // g, 0)
+    kv_group = 1 if fused else g                  # query heads per KV head
+
+    def q_map(b_, h, j, tbl, cl):
+        return (b_, h, 0, 0)
+
+    def kv_map(b_, h, j, tbl, cl):
+        return (tbl[b_, j], h // kv_group, 0, 0)
+
     kern = functools.partial(_paged_decode_kernel, scale=d ** -0.5,
                              softcap=softcap, bs=bs, nblk=m)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b, hg, m),
         in_specs=[
-            pl.BlockSpec((1, 1, gq, d), lambda b_, h, j, tbl, cl:
-                         (b_, h, 0, 0)),
-            pl.BlockSpec((1, bs, 1, d), kv_map),
-            pl.BlockSpec((1, bs, 1, d), kv_map),
+            pl.BlockSpec((None, None, gq, d), q_map),
+            pl.BlockSpec((None, None, bs, d), kv_map),
+            pl.BlockSpec((None, None, bs, d), kv_map),
         ],
-        out_specs=pl.BlockSpec((1, 1, gq, d), lambda b_, h, j, tbl, cl:
-                               (b_, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((gq,), jnp.float32),
-            pltpu.VMEM((gq,), jnp.float32),
-            pltpu.VMEM((gq, d), jnp.float32),
-        ],
+        out_specs=pl.BlockSpec((None, None, gq, d), q_map),
+        scratch_shapes=_paged_scratch(gq, d),
     )
     out = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hg, gq, d), q.dtype),
         interpret=interpret,
+        name="paged_attention",
     )(block_tables, context_lens, qg, k_pool, v_pool)
     return out.reshape(b, hq, 1, d)
 
@@ -378,4 +381,5 @@ def flash_attention_bhsd(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((bq, d), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention",
     )(q, k, v)

@@ -1,9 +1,13 @@
 """jit'd public wrappers for the Pallas kernels.
 
-Handles layout (models use (B,S,H,D); kernels want (B,H,S,D)), padding to
-block multiples, and the interpret-mode switch: on CPU (this container) the
-kernels execute via ``interpret=True``; on TPU backends they compile to
-Mosaic.
+Handles layout (models use (B,S,H,D); kernels want (B,H,S,D)) and padding
+to block multiples.  This module also makes the one decision of how
+attention runs on each platform (:func:`serving_impl`,
+:func:`_interpret`): on the TPU the kernels compile to Mosaic; on the CPU
+the serving stack takes the XLA path and a kernel runs in Pallas
+interpret mode only when its caller asks for it (``interpret=True``, the
+kernel tests); any other platform is an error, never a silent
+interpreter.
 """
 from __future__ import annotations
 
@@ -19,8 +23,30 @@ from repro.kernels import rglru_scan as _rg
 from repro.kernels import rwkv6_scan as _rw
 
 
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
+def serving_impl() -> str:
+    """The attention implementation the serving stack runs here:
+    ``"pallas"`` (Mosaic kernels) on the TPU, ``"xla"`` on the CPU."""
+    platform = jax.default_backend()
+    if platform == "tpu":
+        return "pallas"
+    if platform == "cpu":
+        return "xla"
+    raise RuntimeError(f"no attention implementation for platform "
+                       f"{platform!r}: kernels compile for the TPU, the "
+                       f"XLA fallback is kept for the CPU")
+
+
+def _interpret(interpret: bool) -> bool:
+    """Run a kernel interpreted only when the caller asked; otherwise it
+    must compile for the TPU."""
+    if interpret:
+        return True
+    platform = jax.default_backend()
+    if platform != "tpu":
+        raise RuntimeError(f"Pallas kernels compile only for the TPU, not "
+                           f"{platform!r}; pass interpret=True to run one "
+                           f"in the Pallas interpreter")
+    return False
 
 
 def _pad_to(x: jax.Array, axis: int, mult: int):
@@ -38,9 +64,9 @@ def _pad_to(x: jax.Array, axis: int, mult: int):
 def flash_attention(q, k, v, *, causal: bool = True,
                     window: Optional[int] = None,
                     softcap: Optional[float] = None, bq: int = 128,
-                    bk: int = 128, interpret: Optional[bool] = None):
+                    bk: int = 128, interpret: bool = False):
     """Model-layout wrapper. q: (B,S,Hq,D); k,v: (B,T,Hkv,D) -> (B,S,Hq,D)."""
-    interpret = _interpret_default() if interpret is None else interpret
+    interpret = _interpret(interpret)
     qt = jnp.swapaxes(q, 1, 2)
     kt = jnp.swapaxes(k, 1, 2)
     vt = jnp.swapaxes(v, 1, 2)
@@ -58,11 +84,11 @@ def flash_attention(q, k, v, *, causal: bool = True,
 @functools.partial(jax.jit, static_argnames=("softcap", "fused", "interpret"))
 def paged_attention(q, k_pool, v_pool, block_tables, context_lens, *,
                     softcap: Optional[float] = None, fused: bool = True,
-                    interpret: Optional[bool] = None):
+                    interpret: bool = False):
     """Decode-time paged attention, model layout.
 
     q: (B, 1, Hq, D) — the current token's query per slot;
-    k_pool, v_pool: (N, bs, Hkv, D) physical KV block pool;
+    k_pool, v_pool: (N, Hkv, bs, D) head-major physical KV block pool;
     block_tables: (B, M) int32; context_lens: (B,) int32.
     Returns (B, 1, Hq, D).  The kernel gathers KV blocks through the block
     table with scalar prefetch, so slots scattered anywhere in the pool cost
@@ -73,7 +99,7 @@ def paged_attention(q, k_pool, v_pool, block_tables, context_lens, *,
     hit the MXU as one (g, d) tile.  ``fused=False`` keeps the per-query-
     head grid for A/B measurement (benchmarks/decode_micro.py).
     """
-    interpret = _interpret_default() if interpret is None else interpret
+    interpret = _interpret(interpret)
     qt = jnp.swapaxes(q, 1, 2)                   # (B, Hq, 1, D)
     out = _fa.paged_attention_bhsd(
         qt, k_pool, v_pool, block_tables.astype(jnp.int32),
@@ -85,12 +111,12 @@ def paged_attention(q, k_pool, v_pool, block_tables, context_lens, *,
 @functools.partial(jax.jit, static_argnames=("softcap", "interpret"))
 def paged_prefill(q, k_pool, v_pool, block_tables, pos0, n_live, *,
                   softcap: Optional[float] = None,
-                  interpret: Optional[bool] = None):
+                  interpret: bool = False):
     """Chunked-prefill paged attention, model layout.
 
     q: (B, C, Hq, D) — one C-token suffix chunk per slot (the chunk's KV
     must already be scattered into the pool);
-    k_pool, v_pool: (N, bs, Hkv, D) physical KV block pool;
+    k_pool, v_pool: (N, Hkv, bs, D) head-major physical KV block pool;
     block_tables: (B, M) int32; pos0, n_live: (B,) int32 (chunk start
     position / live token count per slot).  Returns (B, C, Hq, D) with
     rows at chunk positions >= n_live exactly zero.
@@ -100,7 +126,7 @@ def paged_prefill(q, k_pool, v_pool, block_tables, pos0, n_live, *,
     previously resident blocks plus the chunk itself (causal), via the
     same GQA-fused scalar-prefetch block-table gather.
     """
-    interpret = _interpret_default() if interpret is None else interpret
+    interpret = _interpret(interpret)
     qt = jnp.swapaxes(q, 1, 2)                   # (B, Hq, C, D)
     out = _fa.paged_prefill_bhsd(
         qt, k_pool, v_pool, block_tables.astype(jnp.int32),
@@ -112,7 +138,7 @@ def paged_prefill(q, k_pool, v_pool, block_tables, pos0, n_live, *,
 @functools.partial(jax.jit, static_argnames=("softcap", "interpret"))
 def paged_verify(q, k_pool, v_pool, block_tables, pos0, n_live, *,
                  softcap: Optional[float] = None,
-                 interpret: Optional[bool] = None):
+                 interpret: bool = False):
     """Speculative-verification paged attention, model layout.
 
     q: (B, C, Hq, D) — per slot, the queries of the current token plus its
@@ -165,7 +191,7 @@ def copy_blocks(leaf, src, dst, *, axis: int = 0):
 def quantize_kv_blocks(blocks):
     """Device-side per-(block, head) int8 quantization of KV blocks.
 
-    ``blocks``: (n, bs, Hkv, D) — KV blocks gathered along the pool's
+    ``blocks``: (n, Hkv, bs, D) — KV blocks gathered along the pool's
     block axis (leaves without a head axis fall back to per-block
     scales).  Returns ``(q int8, scales float32 keepdims)``.  The wire
     half of compressed KV transfer (docs/architecture.md ADR-009): a
@@ -189,12 +215,12 @@ def dequantize_kv_blocks(q, scales, *, dtype=jnp.bfloat16):
 
 @functools.partial(jax.jit, static_argnames=("bs", "br", "interpret"))
 def rglru_scan(a, b, h0=None, *, bs: int = 256, br: int = 128,
-               interpret: Optional[bool] = None):
+               interpret: bool = False):
     """a, b: (B,S,R) recurrence coefficients; h0: (B,R) or None.
 
     Returns (h_seq (B,S,R), h_last (B,R) fp32).
     """
-    interpret = _interpret_default() if interpret is None else interpret
+    interpret = _interpret(interpret)
     bsz, s, r = a.shape
     if h0 is None:
         h0 = jnp.zeros((bsz, r), jnp.float32)
@@ -215,12 +241,12 @@ def rglru_scan(a, b, h0=None, *, bs: int = 256, br: int = 128,
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def rwkv6_scan(r, k, v, w, u, s0=None, *, chunk: int = 128,
-               interpret: Optional[bool] = None):
+               interpret: bool = False):
     """Model-layout wrapper. r,k,v,w: (B,S,H,N); u: (H,N); s0: (B,H,N,N).
 
     Returns (y (B,S,H,N), s_final (B,H,N,N) fp32).
     """
-    interpret = _interpret_default() if interpret is None else interpret
+    interpret = _interpret(interpret)
     b, s, h, n = r.shape
     if s0 is None:
         s0 = jnp.zeros((b, h, n, n), jnp.float32)

@@ -37,14 +37,22 @@ def flash_attention_ref(q, k, v, *, causal=True, window: Optional[int] = None,
     return out.astype(q.dtype)
 
 
+def _pages_by_head(pool, block_tables):
+    """(N, Hkv, bs, D) pool -> each slot's blocks in logical order,
+    (B, Hkv, M*bs, D)."""
+    pages = pool[block_tables]                           # (B, M, Hkv, bs, D)
+    b, m, hkv, bs, d = pages.shape
+    return jnp.swapaxes(pages, 1, 2).reshape(b, hkv, m * bs, d)
+
+
 def paged_attention_ref(q, k_pool, v_pool, block_tables, context_lens, *,
                         softcap: Optional[float] = None):
     """Decode-time paged attention oracle (block-table gather, materialized).
 
-    q: (B, Hq, D) one query per slot; k_pool, v_pool: (N, bs, Hkv, D) the
-    shared KV block pool; block_tables: (B, M) int32 physical block ids in
-    logical order; context_lens: (B,) int32 tokens written per slot (the
-    per-slot cursor + 1).  Returns (B, Hq, D).  Positions >= context_lens[i]
+    q: (B, Hq, D) one query per slot; k_pool, v_pool: (N, Hkv, bs, D) the
+    shared head-major KV block pool; block_tables: (B, M) int32 physical
+    block ids in logical order; context_lens: (B,) int32 tokens written per
+    slot (the per-slot cursor + 1).  Returns (B, Hq, D).  Positions >= context_lens[i]
     (including every slot of an unused table entry) are masked out, so stale
     pool contents can never leak into a slot's output.
 
@@ -54,17 +62,17 @@ def paged_attention_ref(q, k_pool, v_pool, block_tables, context_lens, *,
     math; tests assert both against it (tests/test_kernels.py).
     """
     b, hq, d = q.shape
-    _, bs, hkv, _ = k_pool.shape
+    hkv = k_pool.shape[1]
     g = hq // hkv
-    k = k_pool[block_tables].reshape(b, -1, hkv, d)      # (B, M*bs, Hkv, D)
-    v = v_pool[block_tables].reshape(b, -1, hkv, d)
-    kk = jnp.repeat(jnp.swapaxes(k, 1, 2), g, axis=1)    # (B, Hq, M*bs, D)
-    vv = jnp.repeat(jnp.swapaxes(v, 1, 2), g, axis=1)
+    k = _pages_by_head(k_pool, block_tables)             # (B, Hkv, M*bs, D)
+    v = _pages_by_head(v_pool, block_tables)
+    kk = jnp.repeat(k, g, axis=1)                        # (B, Hq, M*bs, D)
+    vv = jnp.repeat(v, g, axis=1)
     scores = jnp.einsum("bhd,bhkd->bhk", q.astype(jnp.float32),
                         kk.astype(jnp.float32)) * (d ** -0.5)
     if softcap is not None:
         scores = softcap * jnp.tanh(scores / softcap)
-    ok = jnp.arange(k.shape[1])[None, :] < context_lens[:, None]   # (B, M*bs)
+    ok = jnp.arange(k.shape[2])[None, :] < context_lens[:, None]   # (B, M*bs)
     scores = jnp.where(ok[:, None, :], scores, -1e30)
     # re-mask after softmax: for a live row this is exact (masked probs
     # underflow to 0.0), while a context_lens==0 row — where softmax
@@ -80,8 +88,9 @@ def paged_prefill_ref(q, k_pool, v_pool, block_tables, pos0, n_live, *,
     """Chunked-prefill paged attention oracle (block-table gather).
 
     q: (B, Hq, C, D) one C-token suffix chunk per slot; k_pool, v_pool:
-    (N, bs, Hkv, D) the shared KV block pool (the chunk's own KV already
-    scattered in); block_tables: (B, M) int32; pos0: (B,) int32 absolute
+    (N, Hkv, bs, D) the shared head-major KV block pool (the chunk's own
+    KV already scattered in); block_tables: (B, M) int32; pos0: (B,) int32
+    absolute
     position of each chunk's first token; n_live: (B,) int32 live tokens
     per chunk (0..C).  Returns (B, Hq, C, D).  Chunk position t attends to
     key positions <= pos0 + t (resident prefix + intra-chunk causal); rows
@@ -90,18 +99,18 @@ def paged_prefill_ref(q, k_pool, v_pool, block_tables, pos0, n_live, *,
     zeroed accumulator for dead rows.
     """
     b, hq, c, d = q.shape
-    _, bs, hkv, _ = k_pool.shape
+    hkv = k_pool.shape[1]
     g = hq // hkv
-    k = k_pool[block_tables].reshape(b, -1, hkv, d)      # (B, M*bs, Hkv, D)
-    v = v_pool[block_tables].reshape(b, -1, hkv, d)
-    kk = jnp.repeat(jnp.swapaxes(k, 1, 2), g, axis=1)    # (B, Hq, M*bs, D)
-    vv = jnp.repeat(jnp.swapaxes(v, 1, 2), g, axis=1)
+    k = _pages_by_head(k_pool, block_tables)             # (B, Hkv, M*bs, D)
+    v = _pages_by_head(v_pool, block_tables)
+    kk = jnp.repeat(k, g, axis=1)                        # (B, Hq, M*bs, D)
+    vv = jnp.repeat(v, g, axis=1)
     scores = jnp.einsum("bhcd,bhkd->bhck", q.astype(jnp.float32),
                         kk.astype(jnp.float32)) * (d ** -0.5)
     if softcap is not None:
         scores = softcap * jnp.tanh(scores / softcap)
     q_pos = pos0[:, None] + jnp.arange(c)[None, :]               # (B, C)
-    k_pos = jnp.arange(k.shape[1])                               # (M*bs,)
+    k_pos = jnp.arange(k.shape[2])                               # (M*bs,)
     ok = (jnp.arange(c)[None, :] < n_live[:, None])[:, :, None] \
         & (k_pos[None, None, :] <= q_pos[:, :, None])            # (B, C, K)
     scores = jnp.where(ok[:, None], scores, -1e30)
@@ -114,14 +123,14 @@ def quantize_kv_blocks_ref(blocks):
     """Loop-form oracle for per-(block, head) int8 KV quantization.
 
     Quantizes each (block, head) slice independently with its own scale
-    ``max|x| / 127``; blocks without a head axis (ndim < 3) get one scale
-    per block.  Returns (q int8, scales float32 keepdims), matching
-    ``ops.quantize_kv_blocks``.
+    ``max|x| / 127``; head-major blocks carry the head axis at -3, and
+    blocks without one (ndim < 4) get one scale per block.  Returns
+    (q int8, scales float32 keepdims), matching ``ops.quantize_kv_blocks``.
     """
     import numpy as np
     v = np.asarray(blocks, dtype=np.float32)
-    if v.ndim >= 3:
-        axes = tuple(i for i in range(1, v.ndim) if i != v.ndim - 2)
+    if v.ndim >= 4:
+        axes = tuple(i for i in range(1, v.ndim) if i != v.ndim - 3)
     else:
         axes = tuple(range(1, v.ndim))
     amax = np.max(np.abs(v), axis=axes, keepdims=True)

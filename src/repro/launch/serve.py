@@ -66,6 +66,7 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import time
 from typing import Callable, Dict, List, Optional
 
 import jax
@@ -77,7 +78,8 @@ from repro.core import (ClonePool, ExecutionController, Policy,
                         RemoteableMethod, TpuEnergyModel)
 from repro.core.clock import VirtualClock
 from repro.core.clones import (CLONE_TYPES, KV_SCALE_BY_CLONE_TYPE,
-                               PAUSE_IDLE_TTL, CircuitBreaker)
+                               PAUSE_IDLE_TTL, CircuitBreaker,
+                               ClonePoolExhausted)
 from repro.core.dispatch import Dispatcher
 from repro.core.faults import CloneFault, FaultInjector
 from repro.core.gateway import StreamingGateway
@@ -86,7 +88,9 @@ from repro.core.scheduler import (AdmissionQueue, FleetAutoscaler,
                                   ServeRequest, SlotLedger, poisson_arrivals)
 from repro.core.venues import (LINKS, Venue, kv_block_bytes, pytree_bytes,
                                transfer_time)
+from repro.kernels import ops as kops
 from repro.launch import steps as S
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import model
 
 
@@ -128,16 +132,21 @@ class LMBackend:
     the string ``"oracle"`` aliases the target itself as its own draft —
     the acceptance-rate-1.0 harness benchmarks and tests corrupt
     deterministically.  ``None`` (default) leaves speculation off.
+
+    Weights are random from ``seed``.  Attention runs as
+    :func:`repro.kernels.ops.serving_impl` decides for the platform:
+    Mosaic kernels on the TPU, the XLA path on the CPU.
     """
 
-    def __init__(self, cfg, capacity: int = 256, draft=None):
+    def __init__(self, cfg, capacity: int = 256, draft=None, seed: int = 0):
         self.cfg = cfg
         self.capacity = capacity
-        self.ctx = S.make_context(None,
+        impl = kops.serving_impl()
+        self.ctx = S.make_context(None, impl=impl,
                                   moe_capacity_factor=(
                                       cfg.n_experts / cfg.top_k
                                       if cfg.is_moe else 1.25))
-        self.params = model.init(cfg, jax.random.PRNGKey(0))
+        self.params = model.init(cfg, jax.random.PRNGKey(seed))
         self.draft_cfg = None
         self.draft_params = None
         self.draft_ctx = None
@@ -151,7 +160,7 @@ class LMBackend:
                     f"({draft.vocab_size} != {cfg.vocab_size}): acceptance "
                     "compares token ids directly")
             self.draft_cfg = draft
-            self.draft_ctx = S.make_context(None,
+            self.draft_ctx = S.make_context(None, impl=impl,
                                             moe_capacity_factor=(
                                                 draft.n_experts / draft.top_k
                                                 if draft.is_moe else 1.25))
@@ -173,6 +182,7 @@ class LMBackend:
         self.decode = jax.jit(decode_fn)
         # locate each cache leaf's batch/capacity axes by diffing shapes
         self._batch_axis, self._cap_axis = model.cache_axes(cfg)
+        self._block_axis = model.paged_block_axes(cfg)
         self._paged_fns: Dict[tuple, tuple] = {}      # (bs, donate)
         self._paged_win_fns: Dict[tuple, object] = {}  # (bs, window, donate)
         self._paged_sfx_fns: Dict[tuple, object] = {}  # (bs, T, C, donate)
@@ -329,7 +339,7 @@ class LMBackend:
         if base_key in self._paged_fns and win_key in self._paged_win_fns:
             return self._paged_fns[base_key] + (self._paged_win_fns[win_key],)
         cfg, ctx = self.cfg, self.ctx
-        b_ax, c_ax = self._batch_axis, self._cap_axis
+        b_ax, c_ax, p_ax = self._batch_axis, self._cap_axis, self._block_axis
         capacity = self.capacity
 
         def prefill_into(params, toks, pool, blk_ids, slots):
@@ -339,19 +349,21 @@ class LMBackend:
                 cache_capacity=nb0 * block_size)
             flat_ids = blk_ids.reshape(-1)
 
-            def scatter(pool_leaf, pre, bax, cax):
+            def scatter(pool_leaf, pre, bax, cax, pax):
                 if cax is None:                      # per-slot state rows
                     lp = jnp.moveaxis(pool_leaf, bax, 0)
                     rows = jnp.moveaxis(pre, bax, 0)
                     return jnp.moveaxis(lp.at[slots].set(rows, mode="drop"),
                                         0, bax)
-                lp = jnp.moveaxis(pool_leaf, (bax, cax), (0, 1))
+                # pool (.., N, Hkv, bs, D) and contiguous cache
+                # (.., J, cap, Hkv, D) both move to (blocks, tokens, ..)
+                lp = jnp.moveaxis(pool_leaf, (bax, pax), (0, 1))
                 pr = jnp.moveaxis(pre, (bax, cax), (0, 1))
                 pr = pr.reshape((j * nb0, block_size) + pr.shape[2:])
                 return jnp.moveaxis(lp.at[flat_ids].set(pr), (0, 1),
-                                    (bax, cax))
+                                    (bax, pax))
 
-            pool = jax.tree.map(scatter, pool, pcache, b_ax, c_ax)
+            pool = jax.tree.map(scatter, pool, pcache, b_ax, c_ax, p_ax)
             return jnp.argmax(logits, -1), pool
 
         def decode_slots(params, pool, tok, pos, tables):
@@ -465,7 +477,6 @@ class LMBackend:
         capacity axis (per-slot state rows pass through untouched) — one
         fused dispatch per CoW batch, see ``ops.copy_blocks``."""
         if self._copy_fns.get(donate) is None:
-            from repro.kernels import ops as kops
             b_ax, c_ax = self._batch_axis, self._cap_axis
 
             def copy_into(pool, src, dst):
@@ -501,7 +512,6 @@ class LMBackend:
         if fns is None:
             fns = self._migrate_fns = {}
         if fns.get(compress) is None:
-            from repro.kernels import ops as kops
             b_ax, c_ax = self._batch_axis, self._cap_axis
 
             def migrate(dst_pool, src_pool, src_ids, dst_ids,
@@ -527,6 +537,16 @@ class LMBackend:
 
             fns[compress] = jax.jit(migrate)
         return fns[compress]
+
+
+def measured_executor(clone, fn, args):
+    """ClientHandler executor that runs each dispatch exactly once and
+    bills the wall seconds it took, up to ``jax.block_until_ready`` — no
+    re-run, no modeled FLOP ratio.  For runs on the device."""
+    del clone
+    t0 = time.perf_counter()
+    out = jax.block_until_ready(fn(*args))
+    return out, time.perf_counter() - t0
 
 
 class ServingEngine:
@@ -2059,7 +2079,7 @@ class ClientHandler:
                 return c
         try:
             clones, _ = self.pool.acquire(t, n=1, exclude_primary=True)
-        except Exception:
+        except ClonePoolExhausted:
             return None
         for c in clones:
             if c is not decode_clone and c.serveable:
@@ -2093,7 +2113,7 @@ class ClientHandler:
                 return c
         try:
             clones, _ = self.pool.acquire(t, n=1, exclude_primary=True)
-        except Exception:
+        except ClonePoolExhausted:
             return None
         for c in clones:
             if c is not verify_clone and c.serveable:
@@ -3504,13 +3524,24 @@ def main() -> None:
                     help="KV cache mode for --handler")
     ap.add_argument("--window", type=int, default=1,
                     help="decode window: tokens fused per device dispatch")
+    ap.add_argument("--full", action="store_true",
+                    help="serve the config at its published widths (random "
+                         "weights from --seed) on a wall clock, instead of "
+                         "the reduced smoke config")
+    ap.add_argument("--seed", type=int, default=0,
+                    help="seed of the random weights")
     args = ap.parse_args()
+    use_compile_cache()
 
-    cfg = reduced_config(get_config(args.arch))
+    cfg = get_config(args.arch)
+    if not args.full:
+        cfg = reduced_config(cfg)
     if args.handler:
-        backend = LMBackend(cfg, capacity=64)
+        backend = LMBackend(cfg, capacity=64, seed=args.seed)
         handler = ClientHandler(backend, max_batch=args.batch, kv=args.kv,
-                                decode_window=args.window)
+                                decode_window=args.window,
+                                executor=(measured_executor if args.full
+                                          else None))
         reqs = poisson_arrivals(args.rate, args.requests,
                                 prompt_len=8, vocab=cfg.vocab_size,
                                 max_new_tokens=args.new_tokens)
@@ -3519,7 +3550,8 @@ def main() -> None:
         print("pool:", report.pool_stats)
         return
 
-    eng = ServingEngine(cfg, policy=Policy(args.policy))
+    eng = ServingEngine(cfg, policy=Policy(args.policy),
+                        backend=LMBackend(cfg, seed=args.seed))
     rng = np.random.default_rng(0)
     reqs = [Request(i, rng.integers(0, cfg.vocab_size, size=12,
                                     dtype=np.int32), args.new_tokens)

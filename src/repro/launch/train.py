@@ -51,11 +51,10 @@ def build_compressed_train_step(cfg, opt_cfg, ctx):
                                    metrics)
             return new_params, new_opt, ef, metrics
 
-        from repro.distributed.sharding import shard_map_compat
-        new_p, new_o, new_ef, metrics = shard_map_compat(
+        new_p, new_o, new_ef, metrics = jax.shard_map(
             local_step, mesh=ctx.mesh,
             in_specs=(P(), P(), P(), P("data")),
-            out_specs=(P(), P(), P(), P()),
+            out_specs=(P(), P(), P(), P()), check_vma=False,
         )(state["params"], state["opt"], state["ef"], batch)
         return {"params": new_p, "opt": new_o, "ef": new_ef}, metrics
 

@@ -75,6 +75,14 @@ def _ring_positions(pos: jax.Array, window: int) -> jax.Array:
     return pos - ((pos - idx) % window)
 
 
+def _gather_pages(pool: jax.Array, block_tables: jax.Array) -> jax.Array:
+    """Head-major block pool (N, Hkv, bs, D) gathered through per-row
+    block tables (B, M) into contiguous (B, M*bs, Hkv, D) KV."""
+    pages = jnp.swapaxes(pool[block_tables], 2, 3)       # (B, M, bs, Hkv, D)
+    b, m, bs = pages.shape[:3]
+    return pages.reshape((b, m * bs) + pages.shape[3:])
+
+
 def attn_apply(params: dict, x: jax.Array, cfg: ModelConfig, ctx: RunContext,
                rope: Tuple[jax.Array, jax.Array], cache: Optional[dict],
                mode: str, prefix_len: int, pos,
@@ -125,8 +133,10 @@ def attn_apply(params: dict, x: jax.Array, cfg: ModelConfig, ctx: RunContext,
 
     new_cache = None
     if mode == "decode" and block_tables is not None:
-        # Paged decode: the cache leaves are a physical block pool
-        # (num_blocks, block_size, Hkv, D) shared by every slot; each row of
+        # Paged decode: the cache leaves are a head-major physical block
+        # pool (num_blocks, Hkv, block_size, D) shared by every slot (one
+        # block of one head is a contiguous (bs, D) tile, the unit the
+        # paged kernels stage into VMEM); each row of
         # the batch is one slot with its own cursor ``pos[i]`` and its own
         # row of ``block_tables``.  The new token's K/V lands in the slot's
         # current block; attention gathers the slot's blocks in logical
@@ -139,17 +149,17 @@ def attn_apply(params: dict, x: jax.Array, cfg: ModelConfig, ctx: RunContext,
         rows = jnp.arange(bsz)
         blk = block_tables[rows, pos // block_size]          # (B,)
         off = pos % block_size
-        ck = cache["k"].at[blk, off].set(k[:, 0])
-        cv = cache["v"].at[blk, off].set(v[:, 0])
-        if ctx.impl == "pallas":
+        ck = cache["k"].at[blk, :, off].set(k[:, 0])
+        cv = cache["v"].at[blk, :, off].set(v[:, 0])
+        if ctx.kernels:
             from repro.kernels import ops as kops
             out = kops.paged_attention(q, ck, cv, block_tables, pos + 1,
                                        softcap=cfg.logit_softcap,
-                                       fused=ctx.paged_fused)
+                                       fused=ctx.paged_fused,
+                                       interpret=ctx.interpret)
         else:
-            hkv_n = ck.shape[2]
-            kg = ck[block_tables].reshape(bsz, -1, hkv_n, ck.shape[3])
-            vg = cv[block_tables].reshape(bsz, -1, hkv_n, cv.shape[3])
+            kg = _gather_pages(ck, block_tables)
+            vg = _gather_pages(cv, block_tables)
             out = attention_xla(q, kg, vg, causal=True, window=None,
                                 softcap=cfg.logit_softcap, q_offset=pos)
         new_cache = {"k": ck, "v": cv}
@@ -180,16 +190,16 @@ def attn_apply(params: dict, x: jax.Array, cfg: ModelConfig, ctx: RunContext,
         blk = jnp.where(writer,
                         jnp.take_along_axis(block_tables, blk_col, axis=1), 0)
         off = jnp.where(writer, wpos % block_size, 0)
-        ck = cache["k"].at[blk, off].set(k)
-        cv = cache["v"].at[blk, off].set(v)
-        if ctx.impl == "pallas":
+        ck = cache["k"].at[blk, :, off].set(k)
+        cv = cache["v"].at[blk, :, off].set(v)
+        if ctx.kernels:
             from repro.kernels import ops as kops
             out = kops.paged_prefill(q, ck, cv, block_tables, pos0, n_live,
-                                     softcap=cfg.logit_softcap)
+                                     softcap=cfg.logit_softcap,
+                                     interpret=ctx.interpret)
         else:
-            hkv_n = ck.shape[2]
-            kg = ck[block_tables].reshape(bsz, -1, hkv_n, ck.shape[3])
-            vg = cv[block_tables].reshape(bsz, -1, hkv_n, cv.shape[3])
+            kg = _gather_pages(ck, block_tables)
+            vg = _gather_pages(cv, block_tables)
             out = attention_xla(q, kg, vg, causal=True, window=None,
                                 softcap=cfg.logit_softcap, q_offset=pos0)
         new_cache = {"k": ck, "v": cv}
@@ -209,11 +219,12 @@ def attn_apply(params: dict, x: jax.Array, cfg: ModelConfig, ctx: RunContext,
                             k_pos=k_pos)
         new_cache = {"k": ck, "v": cv}
     else:
-        if ctx.impl == "pallas" and cfg.causal and prefix_len == 0:
+        if ctx.kernels and cfg.causal and prefix_len == 0:
             from repro.kernels import ops as kops
             out = kops.flash_attention(q, k, v, causal=True,
                                        window=cfg.window,
-                                       softcap=cfg.logit_softcap)
+                                       softcap=cfg.logit_softcap,
+                                       interpret=ctx.interpret)
         else:
             out = attention_xla(q, k, v, causal=cfg.causal, window=cfg.window,
                                 prefix_len=prefix_len,

@@ -18,7 +18,9 @@ class RunContext:
     mesh: Optional[jax.sharding.Mesh] = None
     dp_axes: Tuple[str, ...] = ("data",)       # ("pod","data") when multi-pod
     model_axis: str = "model"
-    impl: str = "xla"                          # xla | pallas
+    # xla | pallas (Mosaic kernels, TPU) | interpret (the same kernels in
+    # the Pallas interpreter: kernel tests on the CPU)
+    impl: str = "xla"
     remat: str = "full"                        # none | dots | full
     # paged decode kernel: GQA-fused flash-decoding grid (B, Hkv, M) vs the
     # per-query-head grid (B, Hq, M) — kept only as the A/B baseline
@@ -34,6 +36,15 @@ class RunContext:
     # "tp" (default: Megatron TP + FSDP) | "zero-sp" (weights FSDP-only,
     # sequence sharded over the model axis; dense archs, prefill/decode)
     sharding_profile: str = "tp"
+
+    @property
+    def kernels(self) -> bool:
+        """Whether attention and scans run the Pallas kernels."""
+        return self.impl != "xla"
+
+    @property
+    def interpret(self) -> bool:
+        return self.impl == "interpret"
 
     @property
     def zero_sp(self) -> bool:

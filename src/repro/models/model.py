@@ -168,12 +168,15 @@ def init_paged_cache(cfg: ModelConfig, max_slots: int, num_blocks: int,
                      block_size: int, dtype=None) -> Dict:
     """Zero paged decode cache: a block pool plus per-slot state rows.
 
-    Attention KV leaves become physical block pools — the contiguous
-    (B, capacity, Hkv, D) storage is replaced by (num_blocks, block_size,
-    Hkv, D); which blocks belong to which slot is the caller's block table.
-    Leaves with no capacity axis (recurrent state) keep one row per slot:
-    (max_slots, ...).  Block id 0 is conventionally the trash block that
-    inactive slots write into; allocators should never hand it out.
+    Attention KV leaves become head-major physical block pools — the
+    contiguous (B, capacity, Hkv, D) storage is replaced by (num_blocks,
+    Hkv, block_size, D), so one block of one KV head is a contiguous
+    (block_size, D) tile: the unit the paged kernels stage into VMEM, and a
+    block shape the TPU compiler accepts.  Which blocks belong to which
+    slot is the caller's block table.  Leaves with no capacity axis
+    (recurrent state) keep one row per slot: (max_slots, ...).  Block id 0
+    is conventionally the trash block that inactive slots write into;
+    allocators should never hand it out.
     """
     if cfg.window is not None:
         raise NotImplementedError("paged KV cache requires full attention "
@@ -182,7 +185,16 @@ def init_paged_cache(cfg: ModelConfig, max_slots: int, num_blocks: int,
     state = init_cache(cfg, max_slots, 1, dtype)
     _, cap_ax = cache_axes(cfg)
     return jax.tree.map(
-        lambda kv, st, ax: kv if ax is not None else st, pool, state, cap_ax)
+        lambda kv, st, ax: st if ax is None else jnp.swapaxes(kv, ax, ax + 1),
+        pool, state, cap_ax)
+
+
+def paged_block_axes(cfg: ModelConfig):
+    """Each :func:`init_paged_cache` leaf's in-block token axis: the
+    contiguous cache's capacity axis, moved behind the KV-head axis
+    (``None`` for per-slot state rows)."""
+    _, cap_ax = cache_axes(cfg)
+    return jax.tree.map(lambda ax: ax + 1, cap_ax)
 
 
 def cache_logical_axes(cfg: ModelConfig):
@@ -344,11 +356,10 @@ def _ce_vocab_sharded(logits: jax.Array, targets: jax.Array,
         ll = jax.lax.psum(jnp.where(ok, ll_loc, 0.0), m)
         return jnp.log(denom) + lmax - ll
 
-    from repro.distributed.sharding import shard_map_compat
-    return shard_map_compat(
+    return jax.shard_map(
         body, mesh=ctx.mesh,
         in_specs=(P(dp, None, m), P(dp, None)),
-        out_specs=P(dp, None))(logits, targets)
+        out_specs=P(dp, None), check_vma=False)(logits, targets)
 
 
 def cross_entropy(logits: jax.Array, targets: jax.Array,
@@ -419,7 +430,7 @@ def decode_step(cfg: ModelConfig, params: Dict, cache: Dict,
     ``pos`` is the decode cursor: a scalar int32 when the whole batch shares
     one position (contiguous cohort cache), or a (B,) int32 vector of
     *per-slot* cursors when ``block_tables`` (B, M) maps each row onto a
-    paged KV block pool (leaves (num_blocks, block_size, Hkv, D) instead of
+    paged KV block pool (leaves (num_blocks, Hkv, block_size, D) instead of
     (B, capacity, Hkv, D)).  Per-slot cursors are what let a late arrival
     join an in-flight batch: rows no longer share a position.
 

@@ -220,10 +220,9 @@ def moe_apply(params: dict, x: jax.Array, cfg: ModelConfig,
                 w_specs["wi"], w_specs["wi"], w_specs["wo"])
     body = functools.partial(_sharded_body, cfg=cfg, ep=ep, model_axis=m,
                              gated=cfg.mlp_gated, capacity=cap, fsdp=fsdp)
-    from repro.distributed.sharding import shard_map_compat
-    y = shard_map_compat(
+    y = jax.shard_map(
         body, mesh=ctx.mesh, in_specs=in_specs,
-        out_specs=P(dp, None, None),
+        out_specs=P(dp, None, None), check_vma=False,
     )(x, weights, ids, params["wi"],
       wg if wg is not None else params["wi"], params["wo"])
     return y, aux

@@ -104,11 +104,12 @@ def rglru_block_apply(params: dict, x: jax.Array, cfg: ModelConfig,
                                 conv_carry)
     if mode == "decode":
         h_seq, h_last = rglru_step(xc, params, cache["h"])
-    elif ctx.impl == "pallas":
+    elif ctx.kernels:
         from repro.kernels import ops as kops
         h0 = cache["h"] if cache is not None else None
         a, b = _gates(xc, params)
-        h_seq, h_last = kops.rglru_scan(a, b, h0=h0)
+        h_seq, h_last = kops.rglru_scan(a, b, h0=h0,
+                                        interpret=ctx.interpret)
         h_seq = h_seq.astype(xc.dtype)
     else:
         h0 = cache["h"] if cache is not None else None

@@ -163,9 +163,10 @@ def rwkv_time_apply(params: dict, x: jax.Array, cfg: ModelConfig,
     s0 = cache["s"] if cache is not None else None
     if mode == "decode":
         y, s_new = wkv6_step(r, k, v, w, params["u"], s0)
-    elif ctx.impl == "pallas":
+    elif ctx.kernels:
         from repro.kernels import ops as kops
-        y, s_new = kops.rwkv6_scan(r, k, v, w, params["u"], s0=s0)
+        y, s_new = kops.rwkv6_scan(r, k, v, w, params["u"], s0=s0,
+                                   interpret=ctx.interpret)
     else:
         y, s_new = wkv6_chunked_ref(r, k, v, w, params["u"], s0,
                                     unroll=ctx.scan_unroll)

@@ -59,7 +59,8 @@ _SUBPROCESS_SRC = textwrap.dedent("""
     step0 = jax.jit(S.build_train_step(cfg, opt_cfg, ctx0))
     s0, m0 = step0(state, batch)
     # 2x4 production-axis mesh
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((2, 4), ("data", "model"))
     ctx1 = S.make_context(mesh, moe_capacity_factor=capf)
     sh = S.state_shardings(cfg, mesh)
     from repro.distributed import sharding as shd

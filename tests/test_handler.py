@@ -1493,3 +1493,26 @@ def test_disagg_affinity_routing_conserves_blocks_and_tokens():
                                      compress=True)
     assert comp["served"] == 6
     assert 0 < comp["xfer_bytes"] < out["xfer_bytes"]
+
+
+@pytest.mark.parametrize("acquire", ["_acquire_prefill_clone",
+                                     "_acquire_draft_clone"])
+def test_partner_acquire_swallows_only_pool_exhaustion(acquire, monkeypatch):
+    """A partner clone the pool cannot provide degrades the engine (None);
+    any other error out of the pool, such as a device or compile failure,
+    propagates instead of being swallowed."""
+    from repro.core.clones import ClonePoolExhausted
+    from repro.launch.serve import ClientHandler
+    h = ClientHandler(FakeBackend(), executor=lambda c, f, a: (f(*a), 0.01))
+
+    def exhausted(*a, **k):
+        raise ClonePoolExhausted("clone pool exhausted")
+
+    def broken(*a, **k):
+        raise RuntimeError("device lost")
+
+    monkeypatch.setattr(h.pool, "acquire", exhausted)
+    assert getattr(h, acquire)(None) is None
+    monkeypatch.setattr(h.pool, "acquire", broken)
+    with pytest.raises(RuntimeError, match="device lost"):
+        getattr(h, acquire)(None)

@@ -66,8 +66,8 @@ def _paged_case(key, b, hq, hkv, d, bs, lens, dtype=jnp.float32, seed=0):
     n_blocks = sum(-(-ln // bs) for ln in lens) + 1      # block 0 = trash
     k1, k2, k3 = jax.random.split(key, 3)
     q = _rand(k1, (b, hq, d), dtype)
-    kp = _rand(k2, (n_blocks, bs, hkv, d), dtype)
-    vp = _rand(k3, (n_blocks, bs, hkv, d), dtype)
+    kp = _rand(k2, (n_blocks, hkv, bs, d), dtype)
+    vp = _rand(k3, (n_blocks, hkv, bs, d), dtype)
     tables = np.zeros((b, max_blk), np.int32)
     nxt = 1
     for i, ln in enumerate(lens):
@@ -128,8 +128,10 @@ def test_paged_attention_matches_contiguous_cache():
     got = ref.paged_attention_ref(q, kp, vp, tables, cls)
     for i, ln in enumerate(lens):
         nb = -(-ln // 4)
-        kc = np.asarray(kp)[np.asarray(tables)[i, :nb]].reshape(-1, 2, 32)
-        vc = np.asarray(vp)[np.asarray(tables)[i, :nb]].reshape(-1, 2, 32)
+        # head-major blocks (nb, Hkv, bs, D) -> contiguous (nb*bs, Hkv, D)
+        kc = np.asarray(kp)[np.asarray(tables)[i, :nb]].swapaxes(1, 2)
+        vc = np.asarray(vp)[np.asarray(tables)[i, :nb]].swapaxes(1, 2)
+        kc, vc = kc.reshape(-1, 2, 32), vc.reshape(-1, 2, 32)
         want = ref.flash_attention_ref(
             q[i:i + 1, :, None],
             jnp.swapaxes(jnp.asarray(kc[None, :ln]), 1, 2),
@@ -154,8 +156,8 @@ def test_paged_attention_ignores_stale_pool_contents(fused):
     # 3's position 7 (offset 3) is unwritten
     blk0 = int(np.asarray(tables)[0, 0])
     blk1 = int(np.asarray(tables)[1, 1])
-    poisoned_k = poisoned_k.at[blk0, 3].set(1e9).at[blk1, 3].set(1e9)
-    poisoned_v = poisoned_v.at[blk0, 3].set(-1e9).at[blk1, 3].set(-1e9)
+    poisoned_k = poisoned_k.at[blk0, :, 3].set(1e9).at[blk1, :, 3].set(1e9)
+    poisoned_v = poisoned_v.at[blk0, :, 3].set(-1e9).at[blk1, :, 3].set(-1e9)
     out1 = ops.paged_attention(q[:, None], poisoned_k, poisoned_v, tables,
                                cls, fused=fused, interpret=True)
     np.testing.assert_allclose(np.asarray(out0), np.asarray(out1))
@@ -189,8 +191,8 @@ def _prefill_case(key, b, hq, hkv, d, bs, c, pos0, dtype=jnp.float32):
     n_blocks = sum(spans) + 1                    # block 0 = trash
     k1, k2, k3 = jax.random.split(key, 3)
     q = _rand(k1, (b, c, hq, d), dtype)
-    kp = _rand(k2, (n_blocks, bs, hkv, d), dtype)
-    vp = _rand(k3, (n_blocks, bs, hkv, d), dtype)
+    kp = _rand(k2, (n_blocks, hkv, bs, d), dtype)
+    vp = _rand(k3, (n_blocks, hkv, bs, d), dtype)
     tables = np.zeros((b, max_blk), np.int32)
     nxt = 1
     for i, nb in enumerate(spans):
@@ -261,8 +263,8 @@ def test_paged_prefill_ignores_stale_pool_contents():
     for i, (p, n) in enumerate(zip(pos0, n_live)):
         for pos in range(p + n, tb.shape[1] * bs):
             blk, off = tb[i, pos // bs], pos % bs
-            pk = pk.at[blk, off].set(1e9)
-            pv = pv.at[blk, off].set(-1e9)
+            pk = pk.at[blk, :, off].set(1e9)
+            pv = pv.at[blk, :, off].set(-1e9)
     out1 = ops.paged_prefill(q, pk, pv, tables, p0, nl, interpret=True)
     np.testing.assert_allclose(np.asarray(out0), np.asarray(out1))
     ref0 = ref.paged_prefill_ref(q.swapaxes(1, 2), kp, vp, tables, p0, nl)
@@ -375,10 +377,11 @@ def test_quantize_kv_blocks_matches_ref(shape, dtype):
     q, scales = ops.quantize_kv_blocks(blocks)
     qr, sr = ref.quantize_kv_blocks_ref(blocks)
     assert q.dtype == jnp.int8 and scales.dtype == jnp.float32
-    # keepdims: scales broadcast against blocks, one per (block, head).
+    # keepdims: scales broadcast against blocks, one per (block, head);
+    # head-major blocks (n, Hkv, bs, D) carry the head axis at -3.
     assert scales.ndim == blocks.ndim and scales.shape[0] == shape[0]
     want_scale_shape = tuple(
-        n if i == 0 or (len(shape) >= 3 and i == len(shape) - 2) else 1
+        n if i == 0 or (len(shape) >= 4 and i == len(shape) - 3) else 1
         for i, n in enumerate(shape))
     assert scales.shape == want_scale_shape
     np.testing.assert_array_equal(np.asarray(q), np.asarray(qr))
@@ -412,3 +415,20 @@ def test_quantize_kv_blocks_range_and_zeros():
     deq = ops.dequantize_kv_blocks(q, scales, dtype=jnp.float32)
     np.testing.assert_array_equal(np.asarray(deq[0]), 0.0)
     np.testing.assert_allclose(np.asarray(deq[1]), 5.0, rtol=1e-5)
+
+
+# --------------------------------------------------------------------------- #
+# the platform decision (one place: ops.serving_impl / ops._interpret)
+# --------------------------------------------------------------------------- #
+def test_cpu_serves_xla_and_runs_kernels_only_when_asked():
+    """On the CPU the serving stack takes the XLA path, and a kernel runs
+    in the Pallas interpreter only when its caller asks: never a silent
+    interpreter standing in for the chip."""
+    assert jax.default_backend() == "cpu"
+    assert ops.serving_impl() == "xla"
+    q, kp, vp, tables, cls = _paged_case(KEY, 2, 4, 2, 16, 4, (3, 7))
+    with pytest.raises(RuntimeError, match="only for the TPU"):
+        ops.paged_attention(q[:, None], kp, vp, tables, cls)
+    out = ops.paged_attention(q[:, None], kp, vp, tables, cls,
+                              interpret=True)
+    assert out.shape == (2, 1, 4, 16)

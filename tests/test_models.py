@@ -134,9 +134,28 @@ def test_pallas_impl_matches_xla_impl():
         l1, _ = model.forward(cfg, params, batch,
                               RunContext(impl="xla"), "train")
         l2, _ = model.forward(cfg, params, batch,
-                              RunContext(impl="pallas"), "train")
+                              RunContext(impl="interpret"), "train")
         np.testing.assert_allclose(float(l1), float(l2), rtol=2e-3,
                                    err_msg=arch)
+
+
+@pytest.mark.parametrize("arch", ["smollm-360m", "qwen2.5-3b",
+                                  "phi3-mini-3.8b"])
+def test_plain_reference_matches_model_forward(arch):
+    """The plain float32 reference (no kernels, cache or shared layer
+    code) gives the model's prefill logits at every prefix length."""
+    from repro.models import reference
+    cfg = reduced_config(get_config(arch))
+    assert reference.supports(cfg)
+    params = model.init(cfg, KEY)
+    toks = jax.random.randint(jax.random.PRNGKey(5), (1, 12), 0,
+                              cfg.vocab_size)
+    ref = reference.forward_logits(cfg, params, toks[0])
+    for n in (1, 7, 12):
+        got, _ = model.forward(cfg, params, {"tokens": toks[:, :n]},
+                               RunContext(), "prefill")
+        np.testing.assert_allclose(np.asarray(got[0]), np.asarray(ref[n - 1]),
+                                   atol=2e-5, rtol=2e-5)
 
 
 def test_moe_capacity_drops_are_bounded():
@@ -302,20 +321,32 @@ def test_prefill_loop_matches_stepwise_prompt_feed():
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
-def _assert_live_blocks_equal(pool_a, pool_b):
-    """Bitwise-compare every pool block except trash block 0.
+# A C-token chunk projects and attends its C tokens in one matmul, the
+# stepwise scan one token at a time, so XLA reduces them in different
+# orders: float32 K/V then agree to rounding (at most 1.3e-6 seen at
+# d_model 64), not bitwise.  1e-5 is ten times that, and far below what a
+# misplaced or missing write costs (O(1) activations).
+CHUNK_KV_ATOL = 1e-5
+
+
+def _assert_live_blocks_equal(pool_a, pool_b, atol=0.0):
+    """Compare every pool block except trash block 0: bitwise by default,
+    within ``atol`` where the two paths reduce in different orders.
 
     Dead rows park their writes in the trash block, whose final contents
     legitimately differ between execution orders — no reader ever gathers
     it for a live position, so it is excluded from identity claims.
-    Cache leaves are (..., N, bs, Hkv, D) with leading layer-group dims,
+    Cache leaves are (..., N, Hkv, bs, D) with leading layer-group dims,
     so the block axis is ndim-4.
     """
     for a, b in zip(jax.tree.leaves(pool_a), jax.tree.leaves(pool_b)):
         ax = a.ndim - 4
         am = np.moveaxis(np.asarray(a), ax, 0)
         bm = np.moveaxis(np.asarray(b), ax, 0)
-        np.testing.assert_array_equal(am[1:], bm[1:])
+        if atol:
+            np.testing.assert_allclose(am[1:], bm[1:], rtol=0, atol=atol)
+        else:
+            np.testing.assert_array_equal(am[1:], bm[1:])
 
 
 def _staged_suffix_state(cfg, ctx, params, prefix_lens, n_tok, bs, cap,
@@ -358,8 +389,8 @@ def _staged_suffix_state(cfg, ctx, params, prefix_lens, n_tok, bs, cap,
 def test_prefill_chunks_matches_prefill_loop(chunk):
     """The chunked suffix scan (⌈T/chunk⌉ steps) must return the same
     first tokens as the stepwise scan (T steps) and leave every live pool
-    block bitwise identical — across ragged suffixes, nonzero start
-    cursors, and a dead (n_tok == 0) pad row."""
+    block equal up to reduction order (``CHUNK_KV_ATOL``) — across ragged
+    suffixes, nonzero start cursors, and a dead (n_tok == 0) pad row."""
     cfg = reduced_config(get_config("smollm-360m"))
     ctx = RunContext()
     params = model.init(cfg, KEY)
@@ -381,7 +412,7 @@ def test_prefill_chunks_matches_prefill_loop(chunk):
     live = n_tok > 0
     np.testing.assert_array_equal(np.asarray(f_chk)[live],
                                   np.asarray(f_ref)[live])
-    _assert_live_blocks_equal(pool_ref, pool_chk)
+    _assert_live_blocks_equal(pool_ref, pool_chk, atol=CHUNK_KV_ATOL)
 
 
 _CHUNK_FIX = []
@@ -398,7 +429,8 @@ def _chunk_fixture():
 
 def _check_chunked_vs_stepwise(prefix_lens, n_tok, chunk, seed=11):
     """Stage seeded prefixes, then assert prefill_chunks == prefill_loop
-    (first tokens on live rows + every live pool block bitwise)."""
+    (first tokens on live rows exact, every live pool block within
+    ``CHUNK_KV_ATOL``)."""
     cfg, ctx, params = _chunk_fixture()
     bs, cap = 4, 32
     n_tok = list(n_tok)
@@ -421,7 +453,7 @@ def _check_chunked_vs_stepwise(prefix_lens, n_tok, chunk, seed=11):
     live = nt > 0
     np.testing.assert_array_equal(np.asarray(f_chk)[live],
                                   np.asarray(f_ref)[live])
-    _assert_live_blocks_equal(pool_ref, pool_chk)
+    _assert_live_blocks_equal(pool_ref, pool_chk, atol=CHUNK_KV_ATOL)
 
 
 def test_prefill_chunks_random_lengths_token_identical():

@@ -12,8 +12,8 @@ from repro.data.pipeline import DataConfig, Pipeline
 from repro.distributed.compression import (dequantize_int8,
                                            init_error_feedback,
                                            quantize_int8)
-from repro.distributed.sharding import (DEFAULT_RULES, abstract_mesh,
-                                        spec_for)
+from repro.distributed.sharding import DEFAULT_RULES, spec_for
+from repro.launch.mesh import make_local_mesh
 from repro.optim import adamw
 from repro.optim.adamw import OptConfig
 
@@ -126,7 +126,7 @@ def test_error_feedback_unbiased_over_steps():
 # sharding rules
 # --------------------------------------------------------------------------- #
 def test_spec_for_divisibility_and_uniqueness():
-    mesh = abstract_mesh((2, 4), ("data", "model"))
+    mesh = jax.sharding.AbstractMesh((2, 4), ("data", "model"))
     P = jax.sharding.PartitionSpec
     # divisible dims get their preferred axes
     assert spec_for((16, 8), ("embed", "mlp"), mesh) == P("data", "model")
@@ -139,7 +139,7 @@ def test_spec_for_divisibility_and_uniqueness():
 
 
 def test_spec_for_batch_tuple_rule():
-    mesh = abstract_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = jax.sharding.AbstractMesh((2, 2, 2), ("pod", "data", "model"))
     P = jax.sharding.PartitionSpec
     assert spec_for((8, 4), ("batch", None), mesh) == P(("pod", "data"))
     # batch=1 cannot shard
@@ -149,8 +149,29 @@ def test_spec_for_batch_tuple_rule():
 def test_param_specs_cover_all_archs():
     from repro.configs import list_archs
     from repro.launch import steps as S
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_local_mesh()
     for arch in list_archs():
         cfg = reduced_config(get_config(arch))
         sh = S.state_shardings(cfg, mesh)       # must not raise
         assert jax.tree.leaves(sh)
+
+
+# --------------------------------------------------------------------------- #
+# persistent compilation cache placement
+# --------------------------------------------------------------------------- #
+def test_compile_cache_follows_env_else_fixed_repo_path(monkeypatch):
+    from repro.launch import compile_cache as cc
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+        jax.config.update("jax_compilation_cache_dir", None)
+        assert cc.use_compile_cache() == "/elsewhere/cache"
+        assert jax.config.jax_compilation_cache_dir is None   # JAX reads env
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = cc.use_compile_cache()
+        assert path == jax.config.jax_compilation_cache_dir
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert path == os.path.join(repo, ".jax_cache")
+        assert path == cc.use_compile_cache()                 # fixed path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
