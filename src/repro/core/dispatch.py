@@ -19,6 +19,7 @@ from typing import Callable, List, Optional, Sequence
 
 from repro.core.clock import VirtualClock
 from repro.core.clones import Clone, ClonePool
+from repro.core.tracing import span
 
 
 @dataclasses.dataclass(eq=False)          # identity semantics: usable as a key
@@ -55,6 +56,10 @@ class CloneTask:
 
     def _complete(self) -> None:
         self.done = True
+        # the fired event's callback is this bound method: dropping the
+        # event breaks the task <-> event cycle, so a finished task (and
+        # the arguments and results it holds) goes with its last reference
+        self._event = None
         for cb in self._callbacks:
             cb(self)
         self._callbacks.clear()
@@ -81,6 +86,9 @@ class Dispatcher:
         host execution scaled to the clone's venue (``Venue.execute``).
         ``extra_delay`` charges provisioning/transfer seconds that must
         elapse on the timeline before execution starts.
+
+        The executor call is the ``serve.dispatch`` host span, with
+        ``label`` and the stats ``fn.trace_meta`` carries, if any.
         """
         if executor is None:
             from repro.core.venues import Venue
@@ -88,7 +96,9 @@ class Dispatcher:
             def executor(c, f, a):
                 return Venue(c.spec).execute(f, *a)
 
-        value, venue_s = executor(clone, fn, args)
+        with span("serve.dispatch", label=label,
+                  **getattr(fn, "trace_meta", {})):
+            value, venue_s = executor(clone, fn, args)
         # fault-injected slowdowns (ADR-006) scale the modeled venue time
         # at the one choke point every dispatch passes through, so test
         # and benchmark executors stay fault-agnostic
@@ -113,6 +123,7 @@ class Dispatcher:
         task.cancelled = True
         if task._event is not None:
             task._event.cancel()
+            task._event = None          # as _complete: no cycle left
         return True
 
     def wait(self, tasks: Sequence[CloneTask]) -> List[CloneTask]:

@@ -74,7 +74,10 @@ class ServeRequest:
     prompt: np.ndarray               # (prompt_len,) int32
     max_new_tokens: int = 16
     arrival_t: float = 0.0           # offered-load timestamp (virtual)
-    admitted_t: Optional[float] = None
+    admitted_t: Optional[float] = None   # offered to the queue
+    # first took an engine slot; kept through preemption and restore, so
+    # slot_t - arrival_t is the request's wait in the queue
+    slot_t: Optional[float] = None
     priority: int = 0
     generated: List[int] = dataclasses.field(default_factory=list)
     first_token_t: Optional[float] = None

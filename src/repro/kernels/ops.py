@@ -182,9 +182,10 @@ def copy_blocks(leaf, src, dst, *, axis: int = 0):
     pair list to a fixed bucket size.  Runs as one fused gather+scatter —
     one dispatch per pool leaf regardless of the number of pairs.
     """
-    moved = jnp.moveaxis(leaf, axis, 0)
-    moved = moved.at[dst].set(moved[src])
-    return jnp.moveaxis(moved, 0, axis)
+    with jax.named_scope("copy_blocks"):
+        moved = jnp.moveaxis(leaf, axis, 0)
+        moved = moved.at[dst].set(moved[src])
+        return jnp.moveaxis(moved, 0, axis)
 
 
 @jax.jit

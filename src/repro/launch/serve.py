@@ -66,6 +66,7 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import functools
 import time
 from typing import Callable, Dict, List, Optional
 
@@ -86,6 +87,7 @@ from repro.core.gateway import StreamingGateway
 from repro.core.scheduler import (AdmissionQueue, FleetAutoscaler,
                                   PlacementEngine, ServeCompletion,
                                   ServeRequest, SlotLedger, poisson_arrivals)
+from repro.core.tracing import span
 from repro.core.venues import (LINKS, Venue, kv_block_bytes, pytree_bytes,
                                transfer_time)
 from repro.kernels import ops as kops
@@ -344,9 +346,10 @@ class LMBackend:
 
         def prefill_into(params, toks, pool, blk_ids, slots):
             j, nb0 = blk_ids.shape
-            logits, pcache = model.forward(
-                cfg, params, {"tokens": toks}, ctx, "prefill",
-                cache_capacity=nb0 * block_size)
+            with jax.named_scope("prefill"):
+                logits, pcache = model.forward(
+                    cfg, params, {"tokens": toks}, ctx, "prefill",
+                    cache_capacity=nb0 * block_size)
             flat_ids = blk_ids.reshape(-1)
 
             def scatter(pool_leaf, pre, bax, cax, pax):
@@ -363,7 +366,8 @@ class LMBackend:
                 return jnp.moveaxis(lp.at[flat_ids].set(pr), (0, 1),
                                     (bax, pax))
 
-            pool = jax.tree.map(scatter, pool, pcache, b_ax, c_ax, p_ax)
+            with jax.named_scope("kv_scatter"):
+                pool = jax.tree.map(scatter, pool, pcache, b_ax, c_ax, p_ax)
             return jnp.argmax(logits, -1), pool
 
         def decode_slots(params, pool, tok, pos, tables):
@@ -1143,6 +1147,7 @@ class _SlotEngine:
         # be in flight concurrently; the run loop pumps whichever side
         # has work and is idle
         self.main_inflight = False
+        self.step_id = 0            # the handler's id of the last step built
         self.disagg_inflight = False
 
     def device_tables(self):
@@ -1571,6 +1576,7 @@ class ClientHandler:
         self.preemptions = 0
         self.restored_tokens = 0
         self.prefix_hit_tokens = 0
+        self.steps_built = 0      # engine steps built; a step's trace id
         self.prompt_tokens = 0
         # fleet economics (ADR-004)
         self.energy_j_by_type: Dict[str, float] = {}
@@ -2141,20 +2147,26 @@ class ClientHandler:
         economics into the handler's report counters.  Disagg-eligible
         cold prompts are intercepted first (ADR-009): they allocate a
         decode-side slot but defer the prefill to the engine's partner
-        clone."""
-        st = self._clone_stat(engine.clone)
-        if self._try_disagg_admit(engine, req):
-            plen = self.prompt_pad      # fresh eff is exactly pad-long
-            self.prompt_tokens += plen
-            st["prompt_tokens"] += plen
-            return
-        info = engine.admit(req, self.prompt_pad)
-        self.prefix_hit_tokens += info["cached"]
-        self.prompt_tokens += info["prompt"]
-        st["prefix_hit_tokens"] += info["cached"]
-        st["prompt_tokens"] += info["prompt"]
-        if info["restore"]:
-            self.restored_tokens += info["suffix"]
+        clone.  Stamps ``req.slot_t`` the first time the request takes a
+        slot (the ``serve.admit`` span)."""
+        if req.slot_t is None:
+            req.slot_t = self.clock.now()
+        with span("serve.admit", rid=req.rid) as sp:
+            st = self._clone_stat(engine.clone)
+            if self._try_disagg_admit(engine, req):
+                plen = self.prompt_pad      # fresh eff is exactly pad-long
+                self.prompt_tokens += plen
+                st["prompt_tokens"] += plen
+                sp.set_metadata(cached=0, prompt=plen)
+                return
+            info = engine.admit(req, self.prompt_pad)
+            self.prefix_hit_tokens += info["cached"]
+            self.prompt_tokens += info["prompt"]
+            st["prefix_hit_tokens"] += info["cached"]
+            st["prompt_tokens"] += info["prompt"]
+            if info["restore"]:
+                self.restored_tokens += info["suffix"]
+            sp.set_metadata(cached=info["cached"], prompt=info["prompt"])
 
     def _try_disagg_admit(self, engine: _SlotEngine,
                           req: ServeRequest) -> bool:
@@ -2287,6 +2299,15 @@ class ClientHandler:
                     counts)
 
     def _submit_engine_step(self, engine: _SlotEngine):
+        """Build the engine's next step (the ``serve.step.build`` span,
+        numbered by ``steps_built``) and dispatch it; returns the task."""
+        self.steps_built += 1
+        engine.step_id = self.steps_built
+        with span("serve.step.build", step=engine.step_id) as sp:
+            submit = self._build_engine_step(engine, sp)
+        return submit()
+
+    def _build_engine_step(self, engine: _SlotEngine, sp) -> Callable:
         """One dispatched unit of engine work: fold every pending join's
         prefill into the step — full batched prefill for cold prompts,
         device block copies for CoW splits, a suffix scan for prefix-hit
@@ -2300,7 +2321,9 @@ class ClientHandler:
         The dispatched closure is *pure* over its bound arguments (the
         Venue executor re-runs it to stabilize timing), so all block/slot
         bookkeeping — including preemption — happens here on the host
-        before submission.
+        before submission.  Returns the call that submits the step; the
+        step's counts go on ``sp`` (the build span) and on the closure's
+        ``trace_meta`` (the dispatch span).
         """
         kv = engine.kv
         spec = engine.spec_on and engine.draft_clone is not None
@@ -2337,6 +2360,7 @@ class ClientHandler:
         engine.submitted_joins = joins
         engine.submitted_sfx = sfx
         engine.submitted_migrations = migs
+        sp.set_metadata(joins=len(joins), sfx=len(sfx), cow=len(cow))
         rows = np.nonzero(kv.active)[0]
         do_decode = rows.size > 0
         engine.decode_rows = rows if do_decode else None
@@ -2486,7 +2510,8 @@ class ClientHandler:
             nbytes += int(stoks.nbytes)
 
         if spec and do_decode:
-            return self._submit_spec_round(
+            return functools.partial(
+                self._submit_spec_round,
                 engine, counts, rows, join_batch, cow_batch, mig_batches,
                 sfx_batch, sfx_steps, tables, pos, nbytes)
 
@@ -2533,17 +2558,25 @@ class ClientHandler:
         # per chunk, so the one-shot batched path must not ride free)
         step_fn.prefill_tokens = (int(join_batch[0].shape[1])
                                   if join_batch is not None else 0)
+        # the dispatch span's stats: live decode rows of the slots
+        step_fn.trace_meta = {"step": engine.step_id, "rows": int(rows.size),
+                              "steps": step_fn.seq_steps,
+                              "slots": kv.max_slots}
         delay = (self.autoscaler.clone_ready_delay(engine.clone,
                                                    self.clock.now())
                  + self._net_s(nbytes) + xfer_s)
-        task = self.dispatcher.submit(
-            engine.clone, step_fn,
-            (self.backend.params, kv.pool, tok, pos, steps_left, tables),
-            executor=self.executor, extra_delay=delay,
-            label="step" if do_decode else "prefill")
-        self._charge(engine.clone, task.venue_seconds)
-        engine.main_inflight = True
-        return task
+
+        def submit():
+            task = self.dispatcher.submit(
+                engine.clone, step_fn,
+                (self.backend.params, kv.pool, tok, pos, steps_left, tables),
+                executor=self.executor, extra_delay=delay,
+                label="step" if do_decode else "prefill")
+            self._charge(engine.clone, task.venue_seconds)
+            engine.main_inflight = True
+            return task
+
+        return submit
 
     # ----------------------------------------------------- disagg prefill
     def _submit_disagg_prefill(self, engine: _SlotEngine):
@@ -2781,7 +2814,16 @@ class ClientHandler:
 
     def _engine_step_done(self, engine: _SlotEngine, task,
                           completions: List[ServeCompletion]) -> bool:
-        """Fold a completed step back into host state.  True while alive."""
+        """Fold a completed step back into host state (the
+        ``serve.step.fold`` span).  True while alive."""
+        n = len(completions)
+        with span("serve.step.fold", step=engine.step_id) as sp:
+            alive = self._fold_engine_step(engine, task, completions)
+            sp.set_metadata(finished=len(completions) - n)
+        return alive
+
+    def _fold_engine_step(self, engine: _SlotEngine, task,
+                          completions: List[ServeCompletion]) -> bool:
         now = self.clock.now()
         firsts, firsts_sfx, nxt, pool = task.value
         kv = engine.kv
@@ -3189,15 +3231,20 @@ class ClientHandler:
         while True:
             now = self.clock.now()
             self._band_cache.clear()        # fresh round, fresh inventory
-            while i < len(reqs) and reqs[i].arrival_t <= now + 1e-12:
-                # arrivals flow through the gateway when one is present
-                # (ADR-007); it decides cache-hit / reject / shed / queue
-                # and releases into self.queue under quota + fair share
-                if self.gateway is not None:
-                    self.gateway.offer(reqs[i], now)
-                else:
-                    self.queue.offer(reqs[i], now)
-                i += 1
+            if i < len(reqs) and reqs[i].arrival_t <= now + 1e-12:
+                with span("serve.arrivals") as sp:
+                    i0 = i
+                    while i < len(reqs) and reqs[i].arrival_t <= now + 1e-12:
+                        # arrivals flow through the gateway when one is
+                        # present (ADR-007); it decides cache-hit / reject
+                        # / shed / queue and releases into self.queue
+                        # under quota + fair share
+                        if self.gateway is not None:
+                            self.gateway.offer(reqs[i], now)
+                        else:
+                            self.queue.offer(reqs[i], now)
+                        i += 1
+                    sp.set_metadata(n=i - i0)
             if self.injector is not None:
                 # recover clones that died since the last round BEFORE
                 # joins/spawns consult the engine set (ADR-006)
@@ -3335,9 +3382,12 @@ class ClientHandler:
                              if t is not None and t > now), default=None)
                 first_done = min(t.done_at for t in inflight)
                 if bound is not None and bound < first_done:
-                    self.clock.advance_to(bound)
+                    with span("serve.wait"):
+                        self.clock.advance_to(bound)
                     continue
-                for task in self.dispatcher.wait_any(list(inflight)):
+                with span("serve.wait"):
+                    done = self.dispatcher.wait_any(list(inflight))
+                for task in done:
                     unit = inflight.pop(task, None)
                     if unit is None:
                         continue          # hedge loser already resolved
@@ -3374,7 +3424,8 @@ class ClientHandler:
                         if self._retire(cohort, completions):
                             inflight[self._submit_decode(cohort)] = cohort
             elif i < len(reqs):
-                self.clock.advance_to(reqs[i].arrival_t)
+                with span("serve.wait"):
+                    self.clock.advance_to(reqs[i].arrival_t)
             elif self.queue.depth > 0:
                 # every clone may be dead/tripped with revival + probe
                 # events pending — advance to the next clock event and

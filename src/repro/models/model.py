@@ -497,9 +497,10 @@ def decode_loop(cfg: ModelConfig, params: Dict, cache: Dict,
         cur = jnp.where(live, jnp.minimum(cur + 1, capacity), cur)
         return (cache, nxt, cur), nxt
 
-    (cache, _, _), toks = jax.lax.scan(
-        step, (cache, tok0, pos.astype(jnp.int32)),
-        jnp.arange(num_steps, dtype=jnp.int32))
+    with jax.named_scope("decode"):
+        (cache, _, _), toks = jax.lax.scan(
+            step, (cache, tok0, pos.astype(jnp.int32)),
+            jnp.arange(num_steps, dtype=jnp.int32))
     return jnp.swapaxes(toks, 0, 1), cache
 
 
@@ -643,8 +644,9 @@ def prefill_chunks(cfg: ModelConfig, params: Dict, cache: Dict,
         first = jnp.where(done_here, nxt, first)
         return (cache, first), None
 
-    (cache, first), _ = jax.lax.scan(
-        step, (cache, first0), jnp.arange(num_steps, dtype=jnp.int32))
+    with jax.named_scope("prefill_chunk"):
+        (cache, first), _ = jax.lax.scan(
+            step, (cache, first0), jnp.arange(num_steps, dtype=jnp.int32))
     return first, cache
 
 
@@ -689,29 +691,30 @@ def mixed_loop(cfg: ModelConfig, params: Dict, cache: Dict,
 
     def step(carry, t):
         cache, tok, cur, first = carry
-        # --- prefill chunk tile (joining rows) ---
-        base = t * chunk
-        tok_c = jax.lax.dynamic_slice_in_dim(stoks, base, chunk, axis=1)
-        n_live = jnp.clip(sfx_n - base, 0, chunk)
-        eff_stables = jnp.where((n_live > 0)[:, None], stables, 0)
-        logits_c, cache = chunk_step(cfg, params, cache, tok_c,
-                                     sfx_pos0 + base, n_live, ctx,
-                                     block_tables=eff_stables,
-                                     block_size=block_size,
-                                     capacity=capacity)
-        nxt_c = jnp.argmax(logits_c, -1).astype(jnp.int32)
-        done_here = (sfx_n > base) & (sfx_n <= base + chunk)
-        first = jnp.where(done_here, nxt_c, first)
-        # --- decode tile (resident cohort) ---
-        live = t < steps_left
-        eff_tables = jnp.where(live[:, None], tables, 0)
-        eff_pos = jnp.where(live, jnp.minimum(cur, capacity - 1), 0)
-        logits, cache = decode_step(cfg, params, cache, tok[:, None],
-                                    eff_pos, ctx, block_tables=eff_tables,
-                                    block_size=block_size)
-        nxt = jnp.argmax(logits, -1).astype(jnp.int32)
-        nxt = jnp.where(live, nxt, tok)
-        cur = jnp.where(live, jnp.minimum(cur + 1, capacity), cur)
+        with jax.named_scope("prefill_chunk"):     # joining rows
+            base = t * chunk
+            tok_c = jax.lax.dynamic_slice_in_dim(stoks, base, chunk, axis=1)
+            n_live = jnp.clip(sfx_n - base, 0, chunk)
+            eff_stables = jnp.where((n_live > 0)[:, None], stables, 0)
+            logits_c, cache = chunk_step(cfg, params, cache, tok_c,
+                                         sfx_pos0 + base, n_live, ctx,
+                                         block_tables=eff_stables,
+                                         block_size=block_size,
+                                         capacity=capacity)
+            nxt_c = jnp.argmax(logits_c, -1).astype(jnp.int32)
+            done_here = (sfx_n > base) & (sfx_n <= base + chunk)
+            first = jnp.where(done_here, nxt_c, first)
+        with jax.named_scope("decode"):            # resident cohort
+            live = t < steps_left
+            eff_tables = jnp.where(live[:, None], tables, 0)
+            eff_pos = jnp.where(live, jnp.minimum(cur, capacity - 1), 0)
+            logits, cache = decode_step(cfg, params, cache, tok[:, None],
+                                        eff_pos, ctx,
+                                        block_tables=eff_tables,
+                                        block_size=block_size)
+            nxt = jnp.argmax(logits, -1).astype(jnp.int32)
+            nxt = jnp.where(live, nxt, tok)
+            cur = jnp.where(live, jnp.minimum(cur + 1, capacity), cur)
         return (cache, nxt, cur, first), nxt
 
     (cache, _, _, first), toks = jax.lax.scan(
