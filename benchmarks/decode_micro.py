@@ -3,12 +3,14 @@
 Measures the two layers of the flash-decoding fast path *directly*, instead
 of through the virtual-clock serving simulation:
 
-1. **Kernel sweep** — ``ops.paged_attention`` fused (grid ``(B, Hkv, M)``,
-   one KV block fetch per GQA group) vs unfused (grid ``(B, Hq, M)``, one
+1. **Kernel sweep** — ``ops.paged_attention`` fused (grid
+   ``(B, ceil(M/P))``: one step per slot and chunk of P blocks, every live
+   block staged once for all KV heads) vs unfused (grid ``(B, Hq, M)``, one
    fetch per query head), swept over GQA group sizes and block sizes.
-   Reports wall time per call and the exact KV-block fetch counts from the
-   kernel grids (``flash_attention.paged_kv_fetches``) — the fused kernel
-   must stage each block once per group, i.e. g x fewer fetches.
+   Reports wall time per call and the exact KV fetch counts, in (block,
+   KV head) tiles, of the kernels (``flash_attention.paged_kv_fetches``):
+   at the sweep's full context the fused kernel stages each block once
+   per group, i.e. g x fewer fetches.
 
 2. **Decode loop** — ``model.decode_loop`` (one ``lax.scan`` dispatch per
    T-token window) vs T calls of the per-token ``decode_slots`` path on the
@@ -101,9 +103,10 @@ def kernel_sweep(cases, *, b: int, ctx_blocks: int, d: int, reps: int,
             "num_blocks": n_blocks, "ctx_tokens": m * bs,
             "fused_us": _time_call(lambda: call(True), reps),
             "unfused_us": _time_call(lambda: call(False), reps),
-            "kv_fetches_fused": paged_kv_fetches(b, hq, hkv, m, fused=True),
-            "kv_fetches_unfused": paged_kv_fetches(b, hq, hkv, m,
-                                                   fused=False),
+            "kv_fetches_fused": paged_kv_fetches(
+                np.asarray(lens), hq, hkv, m, bs, d, 4, fused=True),
+            "kv_fetches_unfused": paged_kv_fetches(
+                np.asarray(lens), hq, hkv, m, bs, d, 4, fused=False),
             "fetch_ratio": g,
         }
         rows.append(row)

@@ -108,24 +108,15 @@ def _paged_scores(q_ref, k_ref, *, scale: float, softcap: Optional[float]):
 def _paged_decode_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref, m_ref,
                          l_ref, acc_ref, *, scale: float,
                          softcap: Optional[float], bs: int, nblk: int):
-    """One (slot, head-group, kv-block) step of decode-time paged attention.
+    """One (slot, query head, kv-block) step of the per-head paged grid.
 
-    One body serves both grids (the per-head grid is exactly the g=1 shape
-    of the fused one).  Fused flash-decoding grid (B, Hkv, M): all
-    ``g = Hq/Hkv`` query heads of a GQA group are computed as one (g, d)
-    tile against each KV block — every block staged HBM->VMEM exactly once
-    per group (g x less KV traffic) and the score matmul is a real
-    (g, d) x (d, bs) MXU tile rather than g separate matvecs.  Per-head
-    A/B grid (B, Hq, M): the same body with g=1 query tiles, re-staging
-    each block once per query head.
-
-    The block table and context lengths arrive as scalar prefetch so the
-    KV BlockSpec index map can chase ``tbl_ref`` — only the blocks a slot
-    actually owns are ever staged into VMEM; there is no materialized
-    (B, M*bs, ...) gather.  The pool is head-major, so one staged block is
-    a contiguous (bs, d) tile of one KV head.  Online-softmax state
-    ((g, 1)/(g, d) m, l, acc) persists in VMEM scratch across the
-    sequential block grid dimension.
+    The A/B baseline of :func:`_paged_decode_chunk_kernel`: the grid
+    (B, Hq, M) gives every query head its own (1, d) tile and re-stages
+    each KV block once per query head.  The block table and context
+    lengths arrive as scalar prefetch so the KV BlockSpec index map can
+    chase ``tbl_ref``; one staged block is a contiguous (bs, d) tile of one
+    KV head.  Online-softmax state ((1, 1)/(1, d) m, l, acc) persists in
+    VMEM scratch across the sequential block grid dimension.
     """
     del tbl_ref                                   # consumed by the index maps
     b = pl.program_id(0)
@@ -147,6 +138,63 @@ def _paged_decode_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref, m_ref,
                              l_ref, acc_ref)
 
     @pl.when(j == nblk - 1)
+    def _finalize():
+        l = jnp.maximum(l_ref[...], 1e-30)
+        o_ref[...] = (acc_ref[...] / l).astype(o_ref.dtype)
+
+
+def _paged_decode_chunk_kernel(plan_ref, len_ref, q_ref, *refs, scale: float,
+                               softcap: Optional[float], bs: int, p: int,
+                               nchunk: int):
+    """One (slot, chunk of P logical blocks) step of decode attention.
+
+    Every KV head of the slot is computed in the step: head h's (g, d)
+    query tile meets the chunk's (P*bs, d) keys and values.  ``refs`` holds
+    P staged K blocks, P staged V blocks, the output and the softmax state;
+    staged block i is one contiguous (Hkv, bs, d) tile of the head-major
+    pool, the physical block ``plan_ref`` names for this step's input i
+    (:func:`_paged_stage_plan`).  Each is its own input, so the pipeline
+    copies the next step's blocks, across slot boundaries too, while this
+    step computes, and copies nothing for an input whose block is the one
+    it already holds.
+
+    A chunk at or past a slot's context computes nothing; positions past
+    it inside the last live chunk are masked.  Their staged values are
+    pool data (finite) and get exactly zero weight.  A slot with no
+    context outputs exact zeros.
+    """
+    k_refs, v_refs = refs[:p], refs[p:2 * p]
+    o_ref, m_ref, l_ref, acc_ref = refs[2 * p:]
+    b = pl.program_id(0)
+    c = pl.program_id(1)
+    ctx = len_ref[b]
+    span = p * bs                                 # tokens one step covers
+    hkv, g = q_ref.shape[0], q_ref.shape[1]
+    del plan_ref                                  # consumed by the index maps
+
+    @pl.when(c == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(c * span < ctx)                      # chunk holds context
+    def _compute():
+        k_pos = c * span + jax.lax.broadcasted_iota(jnp.int32, (g, span), 1)
+        for h in range(hkv):
+            k = jnp.concatenate([r[h].astype(jnp.float32) for r in k_refs])
+            v = jnp.concatenate([r[h].astype(jnp.float32) for r in v_refs])
+            s = jax.lax.dot_general(q_ref[h].astype(jnp.float32), k,
+                                    (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            s = s * scale
+            if softcap is not None:
+                s = softcap * jnp.tanh(s / softcap)
+            s = jnp.where(k_pos < ctx, s, _NEG_INF)
+            _online_softmax_step(s, v, m_ref.at[h], l_ref.at[h],
+                                 acc_ref.at[h])
+
+    @pl.when(c == nchunk - 1)
     def _finalize():
         l = jnp.maximum(l_ref[...], 1e-30)
         o_ref[...] = (acc_ref[...] / l).astype(o_ref.dtype)
@@ -276,17 +324,111 @@ def paged_prefill_bhsd(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     return out.reshape(b, hkv, c, g, d).swapaxes(2, 3).reshape(b, hq, c, d)
 
 
-def paged_kv_fetches(b: int, hq: int, hkv: int, m: int, *,
-                     fused: bool = True) -> int:
-    """KV blocks staged HBM->VMEM per decode step, per pool tensor.
+# tokens of one slot a step of the decode kernel covers, and the VMEM its
+# double-buffered K and V staging may take
+_CHUNK_TOKENS = 128
+_STAGING_BYTES = 4 << 20
 
-    Exactly the paged grid volume: the fused kernel walks (B, Hkv, M) and
-    fetches each (slot, block) once per *group*; the per-head kernel walks
-    (B, Hq, M) and re-stages every block g = Hq/Hkv times.  The benchmark
-    (benchmarks/decode_micro.py) reports this, so it must stay in lockstep
-    with the grids below.
+
+def paged_chunk_blocks(m: int, hkv: int, bs: int, d: int,
+                       itemsize: int) -> int:
+    """P, the logical blocks one step of the decode kernel stages per slot:
+    the largest P <= M with P*bs <= 128 tokens whose double-buffered K and
+    V staging, 4*P*Hkv*bs*d*itemsize bytes, fits 4 MiB of VMEM."""
+    p = max(1, min(m, _CHUNK_TOKENS // bs))
+    while p > 1 and 4 * p * hkv * bs * d * itemsize > _STAGING_BYTES:
+        p -= 1
+    return p
+
+
+def _paged_stage_plan(block_tables, context_lens, *, p: int, bs: int):
+    """(B * ceil(M/P) * P,) int32: the physical block input i of grid step
+    (b, c) stages, at flat index (b * ceil(M/P) + c) * P + i.
+
+    Where logical block c*P + i holds context, it is that block.  Anywhere
+    else (past a slot's context, a slot without one, M % P != 0) input i
+    keeps the block it staged last, or, before its first live block, that
+    block, so the pipeline copies nothing there; an input no context
+    reaches stages its table entry once.
     """
-    return b * (hkv if fused else hq) * m
+    b, m = block_tables.shape
+    nchunk = -(-m // p)
+    j = jnp.arange(nchunk * p, dtype=jnp.int32)
+    blk = block_tables[:, jnp.minimum(j, m - 1)].reshape(b * nchunk, p)
+    n_live = (context_lens + bs - 1) // bs        # blocks a context spans
+    live = (j[None, :] < n_live[:, None]).reshape(b * nchunk, p)
+    step = jnp.arange(b * nchunk, dtype=jnp.int32)[:, None]
+    last = jax.lax.cummax(jnp.where(live, step, -1), axis=0)
+    first = jnp.argmax(live, axis=0).astype(jnp.int32)[None, :]
+    src = jnp.where(last >= 0, last, first)
+    return jnp.take_along_axis(blk, src, axis=0).reshape(-1)
+
+
+def paged_kv_fetches(context_lens, hq: int, hkv: int, m: int, bs: int,
+                     d: int, itemsize: int, *, fused: bool = True) -> int:
+    """(block, KV head) tiles staged HBM->VMEM per decode step, per pool
+    tensor, for slots holding ``context_lens`` tokens.
+
+    The fused kernel (grid (B, ceil(M/P))) stages each block a slot's
+    context spans once, all Hkv heads of it as one (Hkv, bs, d) tile:
+    ceil(ctx/bs) blocks a slot, none for a slot without context.  Each of
+    its P block inputs that no context reaches stages one block in the
+    pipeline's first step.  The per-head kernel walks (B, Hq, M) and
+    stages every block of the table once per query head, live or not.  At
+    full context (every ``ctx == M*bs``) per-head = g x fused.  The
+    benchmark (benchmarks/decode_micro.py) reports this, so it must stay
+    in lockstep with the grids below.
+    """
+    if not fused:
+        return len(context_lens) * hq * m
+    live = [min(max(-(-int(n) // bs), 0), m) for n in context_lens]
+    p = paged_chunk_blocks(m, hkv, bs, d, itemsize)
+    return hkv * (sum(live) + p - min(p, max(live, default=0)))
+
+
+def _paged_attention_chunked(q, k_pool, v_pool, block_tables, context_lens,
+                             *, softcap, interpret):
+    """The fused decode grid (B, ceil(M/P)); see
+    :func:`_paged_decode_chunk_kernel`."""
+    b, hq, _, d = q.shape
+    _, hkv, bs, _ = k_pool.shape
+    m = block_tables.shape[1]
+    g = hq // hkv
+    p = paged_chunk_blocks(m, hkv, bs, d, k_pool.dtype.itemsize)
+    nchunk = -(-m // p)
+    plan = _paged_stage_plan(block_tables, context_lens, p=p, bs=bs)
+    qg = q[:, :, 0, :].reshape(b, hkv, g, d)
+
+    def q_map(b_, c, *_):
+        return (b_, 0, 0, 0)
+
+    def kv_map(i):
+        def index(b_, c, plan_ref, lens_ref):
+            return (plan_ref[(b_ * nchunk + c) * p + i], 0, 0, 0)
+        return pl.BlockSpec((None, hkv, bs, d), index)
+
+    kern = functools.partial(_paged_decode_chunk_kernel, scale=d ** -0.5,
+                             softcap=softcap, bs=bs, p=p, nchunk=nchunk)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(b, nchunk),
+        in_specs=[pl.BlockSpec((None, hkv, g, d), q_map)]
+        + [kv_map(i) for i in range(p)] * 2,
+        out_specs=pl.BlockSpec((None, hkv, g, d), q_map),
+        scratch_shapes=[pltpu.VMEM((hkv, g, 1), jnp.float32),
+                        pltpu.VMEM((hkv, g, 1), jnp.float32),
+                        pltpu.VMEM((hkv, g, d), jnp.float32)],
+    )
+    out = pl.pallas_call(
+        kern,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=interpret,
+        name="paged_attention",
+    )(plan, context_lens, qg, *[k_pool] * p, *[v_pool] * p)
+    return out.reshape(b, hq, 1, d)
 
 
 def paged_attention_bhsd(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
@@ -300,46 +442,47 @@ def paged_attention_bhsd(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     block_tables: (B, M) int32 — per-slot physical block ids, logical order;
     context_lens: (B,) int32 — tokens valid per slot.  Returns (B, Hq, 1, D).
 
-    ``fused=True`` (default) runs the flash-decoding grid (B, Hkv, M): all
-    g = Hq/Hkv query heads of a GQA group computed per KV block fetch.
-    ``fused=False`` keeps the original per-query-head grid (B, Hq, M) for
-    A/B measurement (see benchmarks/decode_micro.py).
+    ``fused=True`` (default) runs the grid (B, ceil(M/P)): one step owns
+    one slot and a chunk of P logical blocks (:func:`paged_chunk_blocks`,
+    from the shapes alone) for all KV heads at once, stages only the
+    blocks that hold context, by DMA, double-buffered across steps, and
+    skips chunks past a slot's context.  ``fused=False`` keeps the
+    per-query-head grid (B, Hq, M) for A/B measurement (see
+    benchmarks/decode_micro.py).
     """
+    if fused:
+        return _paged_attention_chunked(q, k_pool, v_pool, block_tables,
+                                        context_lens, softcap=softcap,
+                                        interpret=interpret)
     b, hq, _, d = q.shape
     _, hkv, bs, _ = k_pool.shape
     m = block_tables.shape[1]
     g = hq // hkv
-    # one kernel body, two grids: fused walks KV heads with (g, d) query
-    # tiles (q regrouped (B, Hq, 1, D) -> (B, Hkv, g, D) so one grid step
-    # owns a whole GQA group); per-head walks query heads with g=1 tiles
-    gq = g if fused else 1                        # query rows per grid step
-    hg = hkv if fused else hq                     # head grid dimension
-    qg = q[:, :, 0, :].reshape(b, hg, gq, d)
-    kv_group = 1 if fused else g                  # query heads per KV head
+    qg = q[:, :, 0, :].reshape(b, hq, 1, d)
 
     def q_map(b_, h, j, tbl, cl):
         return (b_, h, 0, 0)
 
     def kv_map(b_, h, j, tbl, cl):
-        return (tbl[b_, j], h // kv_group, 0, 0)
+        return (tbl[b_, j], h // g, 0, 0)
 
     kern = functools.partial(_paged_decode_kernel, scale=d ** -0.5,
                              softcap=softcap, bs=bs, nblk=m)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, hg, m),
+        grid=(b, hq, m),
         in_specs=[
-            pl.BlockSpec((None, None, gq, d), q_map),
+            pl.BlockSpec((None, None, 1, d), q_map),
             pl.BlockSpec((None, None, bs, d), kv_map),
             pl.BlockSpec((None, None, bs, d), kv_map),
         ],
-        out_specs=pl.BlockSpec((None, None, gq, d), q_map),
-        scratch_shapes=_paged_scratch(gq, d),
+        out_specs=pl.BlockSpec((None, None, 1, d), q_map),
+        scratch_shapes=_paged_scratch(1, d),
     )
     out = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hg, gq, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, hq, 1, d), q.dtype),
         interpret=interpret,
         name="paged_attention",
     )(block_tables, context_lens, qg, k_pool, v_pool)

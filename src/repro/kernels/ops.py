@@ -94,10 +94,11 @@ def paged_attention(q, k_pool, v_pool, block_tables, context_lens, *,
     table with scalar prefetch, so slots scattered anywhere in the pool cost
     the same as a contiguous cache.
 
-    ``fused=True`` (default) is the flash-decoding grid: each KV block is
-    staged once per GQA *group* and all g = Hq/Hkv query heads of the group
-    hit the MXU as one (g, d) tile.  ``fused=False`` keeps the per-query-
-    head grid for A/B measurement (benchmarks/decode_micro.py).
+    ``fused=True`` (default) is the chunked flash-decoding grid: one step
+    per slot and chunk of P blocks, each live KV block staged once for all
+    KV heads, the g = Hq/Hkv query heads of a KV head one (g, d) tile.
+    ``fused=False`` keeps the per-query-head grid for A/B measurement
+    (benchmarks/decode_micro.py).
     """
     interpret = _interpret(interpret)
     qt = jnp.swapaxes(q, 1, 2)                   # (B, Hq, 1, D)

@@ -57,9 +57,9 @@ def paged_attention_ref(q, k_pool, v_pool, block_tables, context_lens, *,
     pool contents can never leak into a slot's output.
 
     This is the single oracle for *both* paged kernel grids — the per-head
-    (B, Hq, M) kernel and the GQA-fused flash-decoding (B, Hkv, M) kernel —
-    because fusion only changes how often a KV block is staged, never the
-    math; tests assert both against it (tests/test_kernels.py).
+    (B, Hq, M) kernel and the chunked (B, ceil(M/P)) kernel — because the
+    grid only changes how often a KV block is staged, never the math;
+    tests assert both against it (tests/test_kernels.py).
     """
     b, hq, d = q.shape
     hkv = k_pool.shape[1]

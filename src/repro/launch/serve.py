@@ -2558,10 +2558,15 @@ class ClientHandler:
         # per chunk, so the one-shot batched path must not ride free)
         step_fn.prefill_tokens = (int(join_batch[0].shape[1])
                                   if join_batch is not None else 0)
-        # the dispatch span's stats: live decode rows of the slots
+        # the dispatch span's stats: live decode rows of the slots, and the
+        # KV blocks their contexts span at the first decode step (what the
+        # decode attention kernel stages, against slots x max_blk reserved)
         step_fn.trace_meta = {"step": engine.step_id, "rows": int(rows.size),
                               "steps": step_fn.seq_steps,
                               "slots": kv.max_slots}
+        if do_decode:
+            ctx = np.minimum(kv.pos[rows], self.backend.capacity - 1) + 1
+            step_fn.trace_meta["kv_blocks"] = int((-(-ctx // kv.bs)).sum())
         delay = (self.autoscaler.clone_ready_delay(engine.clone,
                                                    self.clock.now())
                  + self._net_s(nbytes) + xfer_s)

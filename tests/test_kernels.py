@@ -85,6 +85,11 @@ def _paged_case(key, b, hq, hkv, d, bs, lens, dtype=jnp.float32, seed=0):
     (3, 6, 2, 32, 8, (1, 17, 32), None),        # boundary + full block
     (1, 3, 3, 16, 4, (11,), 20.0),              # softcap, MHA (group 1)
     (2, 8, 2, 16, 4, (3, 9), None),             # GQA 4:1
+    # M = 20 blocks over P = 16: a partial second chunk; 0-context slots
+    # between a full one (ctx = M*bs) and one ending mid-block, mid-chunk
+    (4, 15, 5, 64, 8, (0, 160, 0, 77), None),   # smollm's GQA 3:1, d 64
+    (3, 8, 8, 96, 8, (130, 0, 200), None),      # MHA at d 96, M = 25
+    (2, 16, 2, 128, 8, (150, 45), 30.0),        # GQA 8:1, d 128, softcap
 ])
 def test_paged_attention_kernel_matches_ref(b, hq, hkv, d, bs, lens, softcap,
                                             dtype, fused):
@@ -115,8 +120,38 @@ def test_paged_attention_fused_matches_per_head_kernel(hq, hkv):
                                atol=2e-6, rtol=2e-6)
     g = hq // hkv
     m = tables.shape[1]
-    assert paged_kv_fetches(3, hq, hkv, m, fused=False) == \
-        g * paged_kv_fetches(3, hq, hkv, m, fused=True)
+    full = [m * 8] * 3
+    assert paged_kv_fetches(full, hq, hkv, m, 8, 16, 4, fused=False) == \
+        g * paged_kv_fetches(full, hq, hkv, m, 8, 16, 4, fused=True)
+
+
+def test_paged_kv_fetches_counts_live_blocks():
+    """The fused kernel stages each block a slot's context spans once, for
+    all KV heads, and nothing for a slot without context; the per-head
+    grid stages every table entry once per query head.  The count is the
+    copies the kernel's stage plan asks of the pipeline: one per input
+    wherever its block differs from the step before, and each input's
+    first."""
+    from repro.kernels.flash_attention import (_paged_stage_plan,
+                                               paged_chunk_blocks,
+                                               paged_kv_fetches)
+    m, bs, hkv, hq, d = 20, 8, 5, 15, 64          # P = 16: chunks of 16 + 4
+    p = paged_chunk_blocks(m, hkv, bs, d, 2)
+    assert p == 16
+    lens = [0, 160, 0, 77, 1, 129]                # 0, 20, 0, 10, 1, 17 blocks
+    assert paged_kv_fetches(lens, hq, hkv, m, bs, d, 2) == hkv * 48
+    assert paged_kv_fetches(lens, hq, hkv, m, bs, d, 2,
+                            fused=False) == 6 * hq * m
+    # inputs no context reaches stage one block each in the first step
+    assert paged_kv_fetches([0, 3, 0], hq, hkv, m, bs, d, 2) == hkv * 16
+    assert paged_kv_fetches([0, 0], hq, hkv, m, bs, d, 2) == hkv * 16
+    tables = jnp.asarray(np.arange(1, 1 + 6 * m, dtype=np.int32)
+                         .reshape(6, m))
+    for ln in (lens, [0, 3, 0, 0, 0, 0], [m * bs] * 6):
+        plan = np.asarray(_paged_stage_plan(
+            tables, jnp.asarray(ln, jnp.int32), p=p, bs=bs)).reshape(-1, p)
+        copies = p + int((plan[1:] != plan[:-1]).sum())
+        assert hkv * copies == paged_kv_fetches(ln, hq, hkv, m, bs, d, 2)
 
 
 def test_paged_attention_matches_contiguous_cache():

@@ -63,16 +63,28 @@ def _mosaic_sites(fn, *args) -> int:
         "tpu_custom_call")
 
 
-@pytest.mark.parametrize("fused", [True, False])
-def test_paged_attention_compiles_for_v5e(one_chip, for_tpu, fused):
+SMOLLM = (16, 80, 5, 15, 64, 1281)     # B, M, Hkv, Hq, D, pool blocks as
+PHI3 = (8, 64, 32, 32, 96, 513)        # served; phi3-mini: MHA at D 96
+
+
+@pytest.mark.parametrize("fused,shape", [
+    pytest.param(True, (B, M, HKV, HQ, D, N_BLOCKS), id="True"),
+    pytest.param(False, (B, M, HKV, HQ, D, N_BLOCKS), id="False"),
+    pytest.param(True, SMOLLM, id="True-smollm"),
+    pytest.param(False, SMOLLM, id="False-smollm"),
+    pytest.param(True, PHI3, id="True-phi3"),
+    pytest.param(False, PHI3, id="False-phi3"),
+])
+def test_paged_attention_compiles_for_v5e(one_chip, for_tpu, fused, shape):
+    b, m, hkv, hq, d, n_blocks = shape
     from repro.kernels import ops
     s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
-    pool = s((N_BLOCKS, HKV, BS, D), BF16)
+    pool = s((n_blocks, hkv, BS, d), BF16)
     assert _mosaic_sites(
         lambda q, k, v, t, n: ops.paged_attention(q, k, v, t, n,
                                                   fused=fused),
-        s((B, 1, HQ, D), BF16), pool, pool, s((B, M), I32),
-        s((B,), I32)) >= 1
+        s((b, 1, hq, d), BF16), pool, pool, s((b, m), I32),
+        s((b,), I32)) >= 1
 
 
 @pytest.mark.parametrize("c", [8, 5])       # prefill chunk; K=4 verify

@@ -85,6 +85,12 @@ def test_each_engine_step_builds_dispatches_and_folds_in_order(tmp_path):
         assert disp["label"] in ("step", "prefill")
         assert 0 <= disp["rows"] <= disp["slots"] == 2
         assert disp["steps"] >= 1
+        if disp["rows"]:                       # a decoding or mixed step
+            # each live row's context spans 1..ceil(capacity / bs) blocks
+            max_blk = -(-h.backend.capacity // h.block_size)
+            assert disp["rows"] <= disp["kv_blocks"] <= disp["rows"] * max_blk
+        else:
+            assert "kv_blocks" not in disp
         assert {"joins", "sfx", "cow"} <= set(build)
     # one thread's spans nest: any two are disjoint or one holds the other
     for line in {sp[0] for sp in spans}:
