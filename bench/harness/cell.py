@@ -14,8 +14,7 @@ from typing import Callable, Dict, List, Optional
 import numpy as np
 
 from harness import flops, spec, traffic
-from harness.reference import (Reference, first_choices, gaps,
-                               served_sequences)
+from harness.reference import first_choices, gaps, served_sequences
 from harness.serving import (CompileMeter, build_backend, serve_window,
                              warm_up)
 from harness.weights import check_layout, make_params
@@ -96,8 +95,9 @@ def check_outputs(backend, cell, run: Run, seed: int, control: bool = False):
     for r, o in enumerate(outs):
         served[r, :len(o)] = o
         valid[r, :len(o)] = True
+    fam = spec.family(cell.model)
     t0 = time.perf_counter()
-    ref = Reference(cell.model).logits(backend.params, seqs, at)
+    ref = fam.Reference(cell.model).logits(backend.params, seqs, at)
     jax.block_until_ready(ref)
     log(f"reference: {len(sample)} rows of {seqs.shape[1]} tokens in "
         f"{time.perf_counter() - t0:.3f} s")
@@ -108,8 +108,8 @@ def check_outputs(backend, cell, run: Run, seed: int, control: bool = False):
     prog = stats(gaps(ref, served, valid))
     ctrl = None
     if control:
-        low = Reference(cell.model, quant="int8").logits(backend.params,
-                                                         seqs, at)
+        low = fam.Reference(cell.model, quant="int8").logits(
+            backend.params, seqs, at)
         ctrl = stats(gaps(ref, first_choices(low), valid))
     return prog, ctrl, int(valid.sum())
 

@@ -1,7 +1,7 @@
 """The benchmark's data: BENCHMARK.json, one file per configuration, one per
-traffic mix, one reader per per-layer metric.  Every lookup is by the name
-that BENCHMARK.json gives, so a later change adds files and entries and
-edits none.
+traffic mix, one reader per per-layer metric, one module per model family.
+Every lookup is by the name that BENCHMARK.json or the configuration file
+gives, so a later change adds files and entries and edits none.
 """
 from __future__ import annotations
 
@@ -9,12 +9,14 @@ import dataclasses
 import importlib.util
 import json
 import os
+from types import ModuleType
 from typing import Callable, Dict, List, Optional
 
 BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROOT = os.path.dirname(BENCH_DIR)
 TRAFFIC_DIR = os.path.join(BENCH_DIR, "traffic")
 METRICS_DIR = os.path.join(BENCH_DIR, "metrics")
+FAMILIES_DIR = os.path.join(BENCH_DIR, "families")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -53,6 +55,7 @@ def load_cell(name: str, root: str = ROOT) -> Cell:
     cfg = {c["name"]: c for c in bench["configs"]}[w["config"]]
     with open(os.path.join(root, cfg["file"])) as f:
         model = json.load(f)
+    family(model)          # an unknown architecture fails before any set-up
     e2e = [m for m in bench["end_to_end"] if _reports(m, name)]
     layer = [m for m in bench["per_layer"] if _reports(m, name)]
     return Cell(name, w["config"], w["traffic"], int(w["chips"]), model,
@@ -69,33 +72,42 @@ def metric_reader(name: str) -> Callable:
     return mod.read
 
 
+_FAMILIES: Dict[str, ModuleType] = {}
+
+
+def family(model: Dict) -> ModuleType:
+    """The family module ``families/<architectures[0]>.py`` of a
+    configuration file, loaded once: its ``program_fields``, ``shapes``,
+    ``Reference`` and work counts.  A file that names no architecture, or
+    one with no module, is an error; there is no default family."""
+    arch = (model.get("architectures") or [None])[0]
+    path = os.path.join(FAMILIES_DIR, f"{arch}.py")
+    if path in _FAMILIES:
+        return _FAMILIES[path]
+    if arch is None or not os.path.isfile(path):
+        names = sorted(f[:-3] for f in os.listdir(FAMILIES_DIR)
+                       if f.endswith(".py"))
+        raise KeyError(f"configuration {model.get('registry')!r} names "
+                       f"architecture {arch!r}: no family module {path}; "
+                       f"known: {names}")
+    spec = importlib.util.spec_from_file_location("bench_family_" + arch,
+                                                  path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    _FAMILIES[path] = mod
+    return mod
+
+
 def program_config(model: Dict, capacity: Optional[int] = None):
     """The program's registered configuration for ``model``, after checking
-    that every size it runs at is the one the file states."""
+    that every size it runs at is the one the file states (the fields its
+    family's ``program_fields`` names)."""
     from repro.configs import get_config
+    want = family(model).program_fields(model, capacity)
     pc = get_config(model["registry"])
-    want = {
-        "d_model": model["hidden_size"], "d_ff": model["intermediate_size"],
-        "n_heads": model["num_attention_heads"],
-        "n_kv_heads": model["num_key_value_heads"],
-        "head_dim": model["head_dim"], "n_layers": model["num_hidden_layers"],
-        "vocab_size": model["vocab_size"],
-        "rope_theta": model["rope_theta"],
-        "tie_embeddings": model["tie_word_embeddings"],
-        "mlp_act": model["hidden_act"], "mlp_gated": True,
-        "norm_type": "rmsnorm", "qkv_bias": False, "window": None,
-        "family": "dense", "causal": True, "dtype": model["torch_dtype"],
-        "logit_softcap": None, "final_softcap": None,
-        "scale_embeddings": False, "block_pattern": ("attn",),
-    }
     got = {k: getattr(pc, k) for k in want}
     bad = {k: (got[k], v) for k, v in want.items() if got[k] != v}
     if bad:
         raise ValueError(f"program config {pc.name} differs from the "
                          f"benchmark's file (program, file): {bad}")
-    window = model.get("sliding_window")
-    if window is not None and capacity is not None and capacity > window:
-        raise ValueError(f"capacity {capacity} exceeds the published "
-                         f"sliding window {window}, which the program does "
-                         "not implement")
     return pc

@@ -1,5 +1,6 @@
 """Random weights from the seed, made by the benchmark in one jitted call
-on the device, in bf16, in the parameter tree the serving program reads.
+on the device, in bf16, in the parameter tree the serving program reads
+(the family module's ``shapes``).
 
 The reference reads the same tree, so neither side takes weights the
 other made.  Matrices are normal with standard deviation 1/sqrt(fan-in);
@@ -14,35 +15,10 @@ from typing import Dict
 import jax
 import jax.numpy as jnp
 
+from harness import spec
 from harness.traffic import rng_for
 
 NORM_STD = 0.1
-
-
-def shapes(m: Dict) -> Dict:
-    """(shape, fan_in) of every leaf; fan_in None marks a norm gain."""
-    d, f, v = m["hidden_size"], m["intermediate_size"], m["vocab_size"]
-    h, g, n = (m["num_attention_heads"], m["num_key_value_heads"],
-               m["head_dim"])
-    L = m["num_hidden_layers"]
-    tree = {
-        "embed": ((v, d), d),
-        "final_norm": {"scale": ((d,), None)},
-        "layers": {
-            "stack": {"b0": {
-                "attn": {"wq": ((L, d, h, n), d), "wk": ((L, d, g, n), d),
-                         "wv": ((L, d, g, n), d), "wo": ((L, h, n, d), h * n)},
-                "ffn": {"wi": ((L, d, f), d), "wg": ((L, d, f), d),
-                        "wo": ((L, f, d), f)},
-                "norm1": {"scale": ((L, d), None)},
-                "norm2": {"scale": ((L, d), None)},
-            }},
-            "rest": {},
-        },
-    }
-    if not m["tie_word_embeddings"]:
-        tree["head"] = ((d, v), d)
-    return tree
 
 
 def _is_leaf(x):
@@ -63,8 +39,9 @@ _MAKERS: Dict[str, object] = {}
 
 
 def make_params(m: Dict, seed: int):
-    """The whole parameter tree for ``--seed``, in one jitted call."""
-    tree = shapes(m)
+    """The whole parameter tree for ``--seed``, in one jitted call; its
+    shapes are those of the configuration's family (``spec.family``)."""
+    tree = spec.family(m).shapes(m)
     fn = _MAKERS.setdefault(repr(tree),
                             jax.jit(functools.partial(_make, tree)))
     return fn(jax.random.PRNGKey(int(rng_for(seed, 0).integers(0, 2 ** 31))))

@@ -5,13 +5,17 @@ in interpret mode.
 """
 from __future__ import annotations
 
+import dataclasses
 import json
 import math
 import os
 import sys
 import time
+from types import SimpleNamespace
 from unittest import mock
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -24,6 +28,7 @@ from harness import cell as C  # noqa: E402
 from harness import flops, serving, spec, traffic  # noqa: E402
 from harness import trace as tr  # noqa: E402
 from harness.clock import WallClock  # noqa: E402
+from harness.weights import make_params  # noqa: E402
 
 SECONDS = 1.5
 # the limit of the cell whose traffic the tiny mix copies
@@ -122,6 +127,196 @@ def test_flop_and_byte_counts_match_hand_worked_shapes():
     assert flops.least_seconds(1.0, 819e9, peak) == pytest.approx(1.0)
     with pytest.raises(KeyError):
         flops.peaks("no such chip")
+
+
+# ----------------------------------------------------------------- families
+SMOLLM = "smollm-360m.chat-shared"
+
+
+def test_the_dense_family_keeps_the_numbers_it_had_in_the_harness():
+    """Counts, weights and reference logits as they were read before the
+    dense family moved out of the harness into its own module."""
+    sm = spec.load_cell(SMOLLM).model
+    assert flops.matmul_flops_per_token(sm) == 723517440.0
+    assert flops.kv_bytes_per_token(sm) == 40960.0
+    assert flops.attention_flops(sm, 512, 1) == 63037440.0
+    assert flops.attention_flops(sm, 0, 512) == 16137584640.0
+    assert flops.attention_bytes(sm, 512, 1) == 21135360.0
+    tiny.register()
+    p = make_params(tiny.MODEL, 7)
+    leaves = jax.tree.leaves(p)
+    assert len(leaves) == 11
+    total = sum(jnp.sum(x.astype(jnp.float32)) for x in leaves)
+    np.testing.assert_allclose(float(total), -91.65525817871094, rtol=1e-6)
+    seqs = np.arange(80).reshape(2, 40) % 512
+    at = np.array([[30, 35], [31, 39]])
+    ref = spec.family(tiny.MODEL).Reference
+    out = ref(tiny.MODEL).logits(p, seqs, at)
+    assert [o.shape for o in out] == [(2, 2, 512)]
+    np.testing.assert_allclose(float(sum(jnp.sum(o) for o in out)),
+                               8.153661727905273, rtol=1e-6)
+    low = ref(tiny.MODEL, quant="int8").logits(p, seqs, at)
+    np.testing.assert_allclose(float(sum(jnp.sum(o) for o in low)),
+                               9.593378067016602, rtol=1e-6)
+
+
+TOY_FAMILY = '''
+"""A family that exists only in a test: it records what the harness asks
+of it and answers with numbers of its own."""
+import jax.numpy as jnp
+
+CALLS = []
+
+
+def program_fields(model, capacity=None):
+    CALLS.append(("program_fields", capacity))
+    if capacity is not None and capacity > model["toy_capacity"]:
+        raise ValueError("capacity over the toy's own")
+    return {"d_model": model["hidden_size"],
+            "n_layers": model["num_hidden_layers"]}
+
+
+def shapes(m):
+    CALLS.append(("shapes",))
+    return {"toy": ((3, m["hidden_size"]), m["hidden_size"]),
+            "gain": ((5,), None)}
+
+
+class Reference:
+    def __init__(self, m, quant=None):
+        CALLS.append(("Reference", quant))
+        self.m = m
+
+    def logits(self, params, seqs, at):
+        CALLS.append(("logits", params["toy"].shape))
+        # every position puts token 7 first
+        return [jnp.zeros(at.shape + (self.m["vocab_size"],)).at[..., 7]
+                .set(1.0)]
+
+
+def matmul_flops_per_token(m):
+    return 11.0
+
+
+def kv_bytes_per_token(m):
+    return 13.0
+
+
+def attention_flops(m, cached, new):
+    return 17.0 * cached + new
+
+
+def attention_bytes(m, cached, new):
+    return 19.0 * cached + new
+'''
+
+
+def test_a_family_joins_through_an_added_module(tmp_path, monkeypatch):
+    """A configuration whose architecture names a module that only an added
+    file holds gets that module's program check, weight tree, reference
+    and counts through the harness's own entry points."""
+    (tmp_path / "ToyForCausalLM.py").write_text(TOY_FAMILY)
+    monkeypatch.setattr(spec, "FAMILIES_DIR", str(tmp_path))
+    tiny.register()
+    model = dict(tiny.MODEL, architectures=["ToyForCausalLM"],
+                 toy_capacity=40)
+    toy = spec.family(model)
+    assert spec.family(model) is toy
+    # program check: the toy's two fields, and its own capacity rule
+    assert spec.program_config(model, 32).name == "bench-tiny"
+    with pytest.raises(ValueError, match="toy's own"):
+        spec.program_config(model, 48)
+    with pytest.raises(ValueError, match="d_model"):
+        spec.program_config(dict(model, hidden_size=65), 32)
+    # weight tree
+    p = make_params(model, 3)
+    assert {k: (v.shape, v.dtype) for k, v in p.items()} == {
+        "toy": ((3, 64), jnp.bfloat16), "gain": ((5,), jnp.bfloat16)}
+    # counts, through the readers' module
+    assert flops.matmul_flops_per_token(model) == 11.0
+    assert flops.kv_bytes_per_token(model) == 13.0
+    assert flops.attention_flops(model, 2, 1) == 35.0
+    assert flops.attention_bytes(model, 2, 1) == 39.0
+    # the outputs check's reference and control
+    mix = tiny.MIX
+    sent = traffic.generate(mix, model, 3, 1.0)
+    done = {s.rid: SimpleNamespace(tokens=[7] * s.max_new_tokens)
+            for s in sent}
+    run = C.Run(model, mix, 1.0, 1.0, sent, done, [], {}, {})
+    cell = tiny.cell(model=model)
+    prog, ctrl, n = C.check_outputs(SimpleNamespace(params=p), cell, run, 3,
+                                    control=True)
+    assert n > 0 and prog == ctrl == {"max": 0.0, "mean": 0.0}
+    assert ("Reference", None) in toy.CALLS
+    assert ("Reference", "int8") in toy.CALLS
+    assert ("logits", (3, 64)) in toy.CALLS
+    for c in done.values():
+        c.tokens[0] = 3
+    prog, _, _ = C.check_outputs(SimpleNamespace(params=p), cell, run, 3)
+    assert prog["max"] > 0
+
+
+def test_an_unknown_architecture_fails_before_any_weight_is_made(tmp_path):
+    model = dict(spec.load_cell(SMOLLM).model,
+                 architectures=["NoSuchForCausalLM"])
+    (tmp_path / "model.json").write_text(json.dumps(model))
+    bench = spec.load_benchmark()
+    bench["configs"] = [dict(bench["configs"][0], file="model.json")]
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
+    with pytest.raises(KeyError, match="NoSuchForCausalLM.*LlamaForCausalLM"):
+        spec.load_cell(SMOLLM, root=str(tmp_path))
+    cell = tiny.cell(model=dict(tiny.MODEL,
+                                architectures=["NoSuchForCausalLM"]))
+    with mock.patch.object(C, "make_params",
+                           side_effect=AssertionError("weights made")):
+        with pytest.raises(KeyError, match="NoSuchForCausalLM"):
+            C.setup(cell, 1, serving.CompileMeter())
+    unnamed = {k: v for k, v in tiny.MODEL.items() if k != "architectures"}
+    for m in (unnamed, dict(tiny.MODEL, architectures=[])):
+        with pytest.raises(KeyError, match="no family module"):
+            spec.program_config(m, 48)
+        with pytest.raises(KeyError):
+            make_params(m, 1)
+
+
+# every field the dense program check compares, and a value that differs
+DENSE_FIELDS = {
+    "d_model": 961, "d_ff": 2561, "n_heads": 16, "n_kv_heads": 3,
+    "head_dim": 60, "n_layers": 31, "vocab_size": 49153,
+    "rope_theta": 500000.0, "tie_embeddings": False, "mlp_act": "gelu",
+    "mlp_gated": False, "norm_type": "layernorm", "qkv_bias": True,
+    "window": 4096, "family": "moe", "causal": False, "dtype": "float32",
+    "logit_softcap": 50.0, "final_softcap": 30.0, "scale_embeddings": True,
+    "block_pattern": ("attn", "rglru"),
+}
+
+
+def test_the_dense_family_checks_every_field_it_checked_before():
+    sm = spec.load_cell(SMOLLM).model
+    fields = spec.family(sm).program_fields(sm, 640)
+    assert set(fields) == set(DENSE_FIELDS)
+    assert spec.program_config(sm, 640).name == "smollm-360m"
+
+
+@pytest.mark.parametrize("field", sorted(DENSE_FIELDS))
+def test_a_registered_config_that_differs_in_one_field_is_refused(
+        field, monkeypatch):
+    from repro import configs
+    sm = spec.load_cell(SMOLLM).model
+    base = configs.get_config("smollm-360m")
+    assert getattr(base, field) != DENSE_FIELDS[field]
+    monkeypatch.setitem(configs._REGISTRY, "bench-differs",
+                        dataclasses.replace(base, name="bench-differs",
+                                            **{field: DENSE_FIELDS[field]}))
+    with pytest.raises(ValueError, match=f"'{field}'"):
+        spec.program_config(dict(sm, registry="bench-differs"), 640)
+
+
+def test_a_capacity_past_the_published_window_is_refused():
+    sm = dict(spec.load_cell(SMOLLM).model, sliding_window=512)
+    assert spec.program_config(sm, 512).name == "smollm-360m"
+    with pytest.raises(ValueError, match="sliding window 512"):
+        spec.program_config(sm, 640)
 
 
 # -------------------------------------------------------------------- trace

@@ -4,7 +4,8 @@ from __future__ import annotations
 import dataclasses
 
 MODEL = {
-    "registry": "bench-tiny", "hidden_size": 64, "intermediate_size": 128,
+    "registry": "bench-tiny", "architectures": ["LlamaForCausalLM"],
+    "hidden_size": 64, "intermediate_size": 128,
     "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16,
     "num_hidden_layers": 2, "vocab_size": 512, "rope_theta": 10000.0,
     "rms_norm_eps": 1e-06, "tie_word_embeddings": True, "hidden_act": "silu",
